@@ -266,6 +266,8 @@ def main() -> None:
                     help="operator variant (auto = planner picks via "
                          "--plan; default: dynamic)")
     args = ap.parse_args()
+    from repro.core.aot import configure_persistent_cache
+    configure_persistent_cache()
 
     # Fail on an unwritable telemetry path now, not after the sweep.
     if args.ndjson:

@@ -227,6 +227,8 @@ def main() -> None:
                     help="print the raw per-stage dict instead of the "
                          "markdown table")
     args = ap.parse_args()
+    from repro.core.aot import configure_persistent_cache
+    configure_persistent_cache()
 
     import jax.numpy as jnp
 

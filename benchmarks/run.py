@@ -135,6 +135,8 @@ def main() -> None:
                              "stream", "lm"],
                     help="run a single benchmark section")
     args = ap.parse_args()
+    from repro.core.aot import configure_persistent_cache
+    configure_persistent_cache()
     runs = 2 if args.fast else 5
     deadline_s = args.deadline_ms / 1e3
     if args.repeats < 1:

@@ -187,6 +187,8 @@ def main() -> None:
 
     # Imported only after the XLA flags are settled (jax init locks them).
     from repro.core import Variant
+    from repro.core.aot import configure_persistent_cache
+    configure_persistent_cache()
     variant = Variant(args.variant) if args.variant else None
     if variant == Variant.AUTO and args.plan == "fixed":
         ap.error("--variant auto needs --plan heuristic or autotune")

@@ -13,15 +13,17 @@ XLA from scratch.
 Three pieces:
 
   * `configure_persistent_cache()` — points JAX's persistent
-    compilation cache at an env-configurable directory
-    (``REPRO_COMPILE_CACHE_DIR``; same resolution discipline as the
-    consts cache: unset -> ``~/.cache/repro/xla``, ""/"0" -> disabled).
-    JAX owns the entry format and writes entries atomically
-    (temp file + rename, like the consts cache's publish step), so
-    concurrent serve processes can share one directory. Safe to call
-    any time: when JAX already memoized its "is the cache enabled?"
-    decision (it checks once, at first compile), the memo is reset so
-    the new directory takes effect.
+    compilation cache at ``JAX_COMPILATION_CACHE_DIR`` when the
+    environment sets it, and otherwise at one fixed directory inside
+    the checkout (``<repo>/.jax_cache``, gitignored). The path is part
+    of every entry's key, so it never depends on a temp name, a pid or
+    the time. Entry points (``chip_smoke.py``, ``repro.launch.serve``,
+    the benchmark mains) call it first thing; the warm-up path calls it
+    again, which is a no-op once configured. JAX owns the entry format
+    and writes entries atomically, so concurrent serve processes can
+    share one directory. When JAX already memoized its "is the cache
+    enabled?" decision (it checks once, at first compile), the memo is
+    reset so the new directory takes effect.
   * `aot_warm(engine, pad_to)` — lower + compile the executor's
     fixed-shape padded dispatch program for ``(pad_to, *rf_shape)``
     WITHOUT executing it, install the executable on the engine (its
@@ -67,27 +69,26 @@ __all__ = ["AotProgram", "WarmPool", "aot_warm", "compile_cache_dir",
            "configure_persistent_cache", "set_compile_cache_dir",
            "warm_pool"]
 
+# The checkout this module runs from (src/repro/core/aot.py -> <repo>):
+# on-disk caches default to fixed, gitignored directories inside it.
+CHECKOUT_DIR = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+DEFAULT_CACHE_DIR = os.path.join(CHECKOUT_DIR, ".jax_cache")
+
 _UNSET = object()
-_cache_dir: Optional[str] = None
-_cache_resolved = False
-_cache_configured: Optional[str] = None
-
-
-def _default_cache_dir() -> Optional[str]:
-    env = os.environ.get("REPRO_COMPILE_CACHE_DIR", _UNSET)
-    if env is _UNSET:
-        return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                            "xla")
-    return env if env and env != "0" else None
+_cache_override = _UNSET
+_cache_configured = _UNSET
 
 
 def compile_cache_dir() -> Optional[str]:
-    """Active persistent-compilation-cache dir (None = disabled)."""
-    global _cache_dir, _cache_resolved
-    if not _cache_resolved:
-        _cache_dir = _default_cache_dir()
-        _cache_resolved = True
-    return _cache_dir
+    """Active persistent-compilation-cache dir (None = disabled).
+
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else `DEFAULT_CACHE_DIR`;
+    `set_compile_cache_dir` overrides both (tests sandbox the cache).
+    """
+    if _cache_override is not _UNSET:
+        return _cache_override
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
 
 
 def set_compile_cache_dir(path: Optional[str]) -> None:
@@ -97,9 +98,8 @@ def set_compile_cache_dir(path: Optional[str]) -> None:
     warm-pool builders call it on every pool, so in practice the next
     warm-up.
     """
-    global _cache_dir, _cache_resolved
-    _cache_dir = path
-    _cache_resolved = True
+    global _cache_override
+    _cache_override = path
 
 
 def configure_persistent_cache() -> Optional[str]:
@@ -109,11 +109,11 @@ def configure_persistent_cache() -> Optional[str]:
     cheap when nothing changed. JAX checks "should I use the cache?"
     once, at the first compilation of the process, and memoizes the
     answer — so enabling the cache *after* something already compiled
-    needs that memo reset, which this handles (the private import is
-    fenced: if a future JAX moves it, the cache silently stays in
-    whatever state the config flags put it, never a crash).
+    needs that memo reset, which this does.
     """
     global _cache_configured
+    from jax.experimental.compilation_cache import compilation_cache
+
     d = compile_cache_dir()
     if d == _cache_configured:
         return d
@@ -126,11 +126,7 @@ def configure_persistent_cache() -> Optional[str]:
         # skip exactly the entries the warm-start contract needs.
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:   # noqa: BLE001 — best-effort; config flags still set
-        pass
+    compilation_cache.reset_cache()
     _cache_configured = d
     return d
 
@@ -145,6 +141,10 @@ class AotProgram:
     compile_s: float         # lower+compile wall time (this process)
     warmup_s: float          # compile_s + the optional first execution
     cache_dir: Optional[str]     # persistent cache in effect, if any
+    # The compiled executable (``as_text()`` shows whether a Pallas
+    # kernel made it into the program: ``tpu_custom_call``).
+    executable: object = dataclasses.field(default=None, repr=False,
+                                           compare=False)
 
 
 def aot_warm(engine, pad_to: int, *, execute: bool = True) -> AotProgram:
@@ -176,7 +176,8 @@ def aot_warm(engine, pad_to: int, *, execute: bool = True) -> AotProgram:
     return AotProgram(
         key=engine.cfg.canonical_hash(), pad_to=pad_to,
         devices=getattr(engine, "n_devices", 1),
-        compile_s=compile_s, warmup_s=warmup_s, cache_dir=cache_dir)
+        compile_s=compile_s, warmup_s=warmup_s, cache_dir=cache_dir,
+        executable=compiled)
 
 
 @dataclasses.dataclass

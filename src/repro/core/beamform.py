@@ -25,9 +25,15 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import cnn_ops
 from repro.core.config import UltrasoundConfig, Variant
+
+# The XLA formulations are the f32 tier. The TPU's default f32 matmul
+# rounds its operands to bf16, so the contractions ask for full f32
+# precision explicitly (a no-op on the CPU).
+F32 = lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -65,8 +71,10 @@ def beamform_cnn(cfg: UltrasoundConfig, consts: Dict[str, jnp.ndarray],
     M = consts["interp_matrix"]                          # (n_c, n_pix, n_s, 2)
     # Two real einsums realize the complex matmul; each is a stack of
     # per-channel (n_pix x n_s) @ (n_s x n_f) matmuls == 1x1 convolutions.
-    a = jnp.einsum("cps,scfr->pfr", M[..., 0], iq)       # M_re * (IQre, IQim)
-    b = jnp.einsum("cps,scfr->pfr", M[..., 1], iq)       # M_im * (IQre, IQim)
+    a = jnp.einsum("cps,scfr->pfr", M[..., 0], iq,
+                   precision=F32)                        # M_re * (IQre, IQim)
+    b = jnp.einsum("cps,scfr->pfr", M[..., 1], iq,
+                   precision=F32)                        # M_im * (IQre, IQim)
     return cnn_ops.cpack(a[..., 0] - b[..., 1], a[..., 1] + b[..., 0])
 
 
@@ -77,9 +85,10 @@ def beamform_cnn(cfg: UltrasoundConfig, consts: Dict[str, jnp.ndarray],
 
 def beamform_sparse(cfg: UltrasoundConfig, consts: Dict[str, jnp.ndarray],
                     iq: jnp.ndarray) -> jnp.ndarray:
-    blocks = consts["bsr_blocks"]                        # (n_c,n_pb,K,bp,bs,2)
+    blocks = consts["bsr_blocks"]                        # (n_c,n_pb,K,bp,2bs)
     col_idx = consts["bsr_col_idx"]                      # (n_c, n_pb, K)
-    n_c, n_pb, K, bp, bs, _ = blocks.shape
+    n_c, n_pb, K, bp, bs2 = blocks.shape
+    bs = bs2 // 2
     n_s, _, n_f, _ = iq.shape
     n_sb = -(-n_s // bs)
 
@@ -90,8 +99,10 @@ def beamform_sparse(cfg: UltrasoundConfig, consts: Dict[str, jnp.ndarray],
     def one_channel(blocks_1, cols_1, iq_1):
         # iq_1: (n_sb, bs, n_f, 2); cols_1: (n_pb, K)
         g = jnp.take(iq_1, cols_1, axis=0)               # (n_pb, K, bs, n_f, 2)
-        a = jnp.einsum("ikps,iksfr->ipfr", blocks_1[..., 0], g)
-        b = jnp.einsum("ikps,iksfr->ipfr", blocks_1[..., 1], g)
+        a = jnp.einsum("ikps,iksfr->ipfr", blocks_1[..., :bs], g,
+                       precision=F32)
+        b = jnp.einsum("ikps,iksfr->ipfr", blocks_1[..., bs:], g,
+                       precision=F32)
         return cnn_ops.cpack(a[..., 0] - b[..., 1], a[..., 1] + b[..., 0])
 
     per_c = jax.vmap(one_channel, in_axes=(0, 0, 2))(

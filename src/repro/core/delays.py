@@ -129,7 +129,9 @@ class BsrOperator:
     gather (the TPU adaptation of the paper's V3: gather granularity is
     raised to MXU-aligned tiles, matmuls stay dense).
 
-    blocks  : (n_c, n_pb, K, bp, bs, 2) f32
+    blocks  : (n_c, n_pb, K, bp, 2 * bs) f32 — [re | im] side by side on
+              the minor axis (a 128-lane row at bs=64, so the TPU layout
+              stores the operator unpadded)
     col_idx : (n_c, n_pb, K) int32 — sample-block column for each stored block
     """
 
@@ -155,13 +157,14 @@ def bsr_operator(cfg: UltrasoundConfig, tables: DelayTables) -> BsrOperator:
     occupied = np.abs(blk).sum(axis=(2, 4, 5)) > 0  # (n_c, n_pb, n_sb)
 
     K = max(int(occupied.sum(axis=2).max()), 1)
-    blocks = np.zeros((n_c, n_pb, K, bp, bs, 2), dtype=np.float32)
+    blocks = np.zeros((n_c, n_pb, K, bp, 2 * bs), dtype=np.float32)
     col_idx = np.zeros((n_c, n_pb, K), dtype=np.int32)
     for c in range(n_c):
         for i in range(n_pb):
             cols = np.nonzero(occupied[c, i])[0]
             for k, sb in enumerate(cols):
-                blocks[c, i, k] = blk[c, i, :, sb]
+                b = blk[c, i, :, sb]                      # (bp, bs, 2)
+                blocks[c, i, k] = np.concatenate([b[..., 0], b[..., 1]], 1)
                 col_idx[c, i, k] = sb
             # unused K-slots keep col 0 with all-zero data (contribute 0)
 

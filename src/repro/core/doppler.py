@@ -68,7 +68,8 @@ def _smooth(cfg: UltrasoundConfig, consts, img: jnp.ndarray) -> jnp.ndarray:
     x = img[None, None, :, :]
     out = lax.conv_general_dilated(
         x, k[None, None, :, :], window_strides=(1, 1), padding="SAME",
-        dimension_numbers=("NCHW", "OIHW", "NCHW"))
+        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        precision=lax.Precision.HIGHEST)   # f32 on the TPU too
     return out[0, 0]
 
 

@@ -238,8 +238,8 @@ class ShardedExecutor:
 
     The acquisition batch axis is split across the "data" mesh axis with
     `NamedSharding`; constants are replicated. One jitted SPMD program
-    serves every call; XLA partitions it so each device runs the stage
-    graph on its batch shard with no cross-device communication (the
+    serves every call; a `shard_map` runs the stage graph on each
+    device's batch shard with no cross-device communication (the
     pipeline is embarrassingly parallel over acquisitions).
 
     ``devices=None`` takes every local device. Uneven batches are
@@ -270,17 +270,16 @@ class ShardedExecutor:
         self.consts = jax.device_put(
             jax.tree.map(jnp.asarray, init_pipeline(self.cfg)),
             self._consts_sharding)
-        mapped = _mapped_graph_fn(self.cfg)
-        if self.cfg.exec_map == "map":
-            # lax.map is a sequential scan GSPMD cannot partition — left
-            # to the partitioner it would all-gather the batch and run
-            # it whole on every device. shard_map keeps the contract:
-            # each device scans only its local batch shard (constant
-            # memory per device, still data-parallel, no collectives).
-            from jax.experimental.shard_map import shard_map
-            mapped = shard_map(mapped, mesh=self.mesh,
+        # Each device maps the graph over its own batch shard
+        # (shard_map), for both exec_maps: the partitioner cannot split
+        # a Pallas kernel (a custom call) or a sequential lax.map scan —
+        # left to it, the batch would be gathered and run whole on every
+        # device. No collectives: acquisitions are independent, so
+        # there is no varying-axis bookkeeping to check (check_vma=False
+        # also lets Pallas outputs, which carry none, through).
+        mapped = jax.shard_map(_mapped_graph_fn(self.cfg), mesh=self.mesh,
                                in_specs=(P(), P("data")),
-                               out_specs=P("data"))
+                               out_specs=P("data"), check_vma=False)
 
         def run(consts, rf_b):
             return mapped(consts, rf_b)

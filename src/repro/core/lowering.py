@@ -343,9 +343,9 @@ def _beamform_sparse_pallas(cfg, consts, iq):
     the IQ sample-axis blocking; the kernel owns the block gather."""
     from repro.kernels.bsr_spmm import bsr_beamform
     from repro.kernels.pallas_compat import block_sample_axis
-    blocks = consts["bsr_blocks"]                       # (n_c,n_pb,K,bp,bs,2)
+    blocks = consts["bsr_blocks"]                       # (n_c,n_pb,K,bp,2bs)
     cols = consts["bsr_col_idx"]                        # (n_c, n_pb, K)
-    iq_b = block_sample_axis(iq, blocks.shape[4])
+    iq_b = block_sample_axis(iq, blocks.shape[4] // 2)
     return bsr_beamform(cols, blocks, iq_b,
                         precision=cfg.precision)[: cfg.n_pix]
 
@@ -374,10 +374,26 @@ def _fused_dynamic_power_pallas(cfg, consts, rf):
 
 
 def _das_pallas_available(cfg: UltrasoundConfig, backend: str) -> bool:
-    # The wrapper pads the pixel axis to the tile size and the kernel
-    # declares no other hard shape constraint, so the fused DAS kernel
-    # is available everywhere (interpret mode off-TPU).
+    # The wrapper pads pixels and channels to the tile sizes and streams
+    # one channel chunk of IQ per grid step, so the DAS kernel fits VMEM
+    # at every geometry (interpret mode off-TPU).
     return True
+
+
+def _fused_pallas_available(cfg: UltrasoundConfig, backend: str) -> bool:
+    # The fused megakernel keeps the whole RF block and the IQ cube
+    # resident in VMEM: on the TPU it is refused where that estimate
+    # exceeds the kernels' scoped-VMEM budget (the paper geometry needs
+    # ~52 MiB against 32 MiB) or where the pixel tile is not a whole
+    # number of 128-lane vregs.
+    if backend != "tpu":
+        return True
+    from repro.kernels.fused_pipeline.kernel import (DEFAULT_BP,
+                                                     fused_vmem_bytes)
+    from repro.kernels.pallas_compat import VMEM_LIMIT_BYTES
+    bp = cfg.fusion_block or DEFAULT_BP
+    return (bp % 128 == 0 and fused_vmem_bytes(
+        cfg.n_l, cfg.n_c, cfg.n_f, cfg.decim) <= VMEM_LIMIT_BYTES)
 
 
 def _bsr_pallas_available(cfg: UltrasoundConfig, backend: str) -> bool:
@@ -420,13 +436,13 @@ def _register_defaults() -> None:
         ("demod", "beamform", "bmode"), "pallas",
         _fused_dynamic_bmode_pallas,
         variant=Variant.DYNAMIC, modality=Modality.BMODE,
-        available=_das_pallas_available,
+        available=_fused_pallas_available,
         precisions=("f32", "bf16", "f16"))
     register_fused_lowering(
         ("demod", "beamform", "power_doppler"), "pallas",
         _fused_dynamic_power_pallas,
         variant=Variant.DYNAMIC, modality=Modality.POWER_DOPPLER,
-        available=_das_pallas_available,
+        available=_fused_pallas_available,
         precisions=("f32", "bf16", "f16"))
 
 

@@ -70,7 +70,7 @@ CONSTS_CACHE_STATS = ConstsCacheStats()
 # Bump whenever the *meaning* of precomputed constants changes (delay-table
 # math, interp-matrix layout, BSR packing) — the config hash alone cannot
 # see code changes, and a stale disk entry would silently corrupt images.
-CONSTS_SCHEMA = "consts-v1"
+CONSTS_SCHEMA = "consts-v2"     # v2: BSR blocks stored [re | im]
 
 # LRU memory tier, bounded in bytes: a paper-scale variant sweep must not
 # pin multi-GB CNN operators for process lifetime. Entries larger than the
@@ -109,8 +109,8 @@ _disk_cache_resolved = False
 def _default_disk_dir() -> Optional[str]:
     env = os.environ.get("REPRO_CONSTS_CACHE_DIR", _UNSET)
     if env is _UNSET:
-        return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                            "consts")
+        from repro.core.aot import CHECKOUT_DIR
+        return os.path.join(CHECKOUT_DIR, ".consts_cache")   # gitignored
     return env if env and env != "0" else None
 
 

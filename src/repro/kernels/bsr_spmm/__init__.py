@@ -1,1 +1,1 @@
-from repro.kernels.bsr_spmm.ops import bsr_beamform, bsr_spmm  # noqa: F401
+from repro.kernels.bsr_spmm.ops import bsr_beamform  # noqa: F401

@@ -1,23 +1,16 @@
-"""Public wrapper for the BSR SpMM kernel (complex, multi-channel DAS V3).
+"""Public wrapper for the BSR beamform kernel (complex, multi-channel DAS V3).
 
-`bsr_spmm` is the raw real-valued primitive. `bsr_beamform` composes it into
-the complex multi-channel beamform used by repro.core's sparse variant:
-channels vmapped, complex arithmetic as four real SpMMs.
+`bsr_beamform` is the complex multi-channel beamform used by repro.core's
+sparse variant: channels, stored blocks and the complex product all run
+inside one pallas_call.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.bsr_spmm import kernel as _k
 from repro.kernels.pallas_compat import auto_interpret
-
-
-def bsr_spmm(cols, blocks, x, *, precision="f32", interpret=None):
-    return _k.bsr_spmm_pallas(
-        cols, blocks.astype(jnp.float32), x.astype(jnp.float32),
-        precision=precision, interpret=auto_interpret(interpret))
 
 
 def bsr_beamform(cols, blocks, iq_b, *, precision="f32", interpret=None):
@@ -25,28 +18,21 @@ def bsr_beamform(cols, blocks, iq_b, *, precision="f32", interpret=None):
 
     Args:
       cols:   (n_c, n_pb, K) int32.
-      blocks: (n_c, n_pb, K, bp, bs, 2) f32 (complex as trailing re/im).
+      blocks: (n_c, n_pb, K, bp, 2 * bs) f32, [re | im] on the minor axis.
       iq_b:   (n_sb, bs, n_c, n_f, 2) f32 blocked IQ.
       precision: SpMM-operand dtype, "f32" | "bf16" | "f16"
         (accumulation is always f32).
     Returns:
       (n_pb * bp, n_f, 2) f32 beamformed output, summed over channels.
     """
-    interpret = auto_interpret(interpret)
-
-    def one_channel(cols_1, blocks_1, iq_1):
-        # iq_1: (n_sb, bs, n_f, 2)
-        a = bsr_spmm(cols_1, blocks_1[..., 0], iq_1[..., 0],
-                     precision=precision, interpret=interpret)   # re*re
-        b = bsr_spmm(cols_1, blocks_1[..., 1], iq_1[..., 1],
-                     precision=precision, interpret=interpret)   # im*im
-        c = bsr_spmm(cols_1, blocks_1[..., 0], iq_1[..., 1],
-                     precision=precision, interpret=interpret)   # re*im
-        d = bsr_spmm(cols_1, blocks_1[..., 1], iq_1[..., 0],
-                     precision=precision, interpret=interpret)   # im*re
-        return jnp.stack([a - b, c + d], axis=-1)   # (n_pb, bp, n_f, 2)
-
-    per_c = jax.vmap(one_channel, in_axes=(0, 0, 2))(cols, blocks, iq_b)
-    y = per_c.sum(axis=0)
-    n_pb, bp = y.shape[0], y.shape[1]
-    return y.reshape(n_pb * bp, *y.shape[2:])
+    iq = jnp.moveaxis(iq_b.astype(jnp.float32), 2, 0)    # (c,sb,bs,f,2)
+    re, im = iq[..., 0], iq[..., 1]
+    x = jnp.concatenate([jnp.concatenate([re, im], -1),
+                         jnp.concatenate([-im, re], -1)], 2)  # (c,sb,2bs,2f)
+    y = _k.bsr_beamform_pallas(
+        cols, blocks.astype(jnp.float32), x, precision=precision,
+        interpret=auto_interpret(interpret))             # (pb, bp, 2f)
+    n_pb, bp, n_f2 = y.shape
+    n_f = n_f2 // 2
+    return jnp.stack([y[..., :n_f], y[..., n_f:]], -1).reshape(
+        n_pb * bp, n_f, 2)

@@ -6,17 +6,25 @@ boundary: RF → IQ (n_s·n_c·n_f·2 floats) → beamformed IQ → head. This
 kernel executes the whole RF-to-{envelope, wall-filtered power} chain in
 ONE pallas_call, keeping every intermediate tile-resident:
 
-  grid = (n_pix // bp,)                     one pixel tile per step
-  step 0:   demod the FULL RF block into a VMEM scratch (the IQ cube is
-            shared by every pixel tile, so it is computed once and
-            persists across the sequential grid — this is the HBM
-            traffic the fusion removes);
-  step i:   build the (bp, n_s) one-hot DAS interpolation weights in
-            VMEM from the compact delay tables (the das_beamform
-            technique), contract them against the scratch IQ on the
-            MXU, rotate/apodize/channel-reduce, then run the head's
-            tile-local half: |z| envelope (bmode) or wall-filter + R0
-            frame power (power_doppler).
+  grid = (n_pix // bp,)   one pixel tile per step, "arbitrary"
+                          (sequential: step 0 fills the IQ scratch)
+  step 0:   demod the FULL RF block into a planar (2, n_c*n_f, n_s) VMEM
+            scratch (the IQ cube is shared by every pixel tile, so it is
+            computed once and persists across the sequential grid — this
+            is the HBM traffic the fusion removes);
+  step i:   for each channel, the das_beamform channel body
+            (`repro.kernels.das_beamform.kernel.beamform_channel`: one-hot
+            interpolation weights built in VMEM, contracted on the MXU,
+            rotated and apodized) accumulates into the (n_f, bp) tile, then
+            the head's tile-local half runs: |z| envelope (bmode) or
+            wall-filter + R0 frame power (power_doppler).
+
+Everything is planar, pixels on the lane axis: tables (n_c, ., n_pix) as
+in das_beamform, RF as (n_c*n_f, n_l), outputs (n_f, n_pix) / (1, n_pix).
+The RF block and the IQ scratch are whole-acquisition VMEM residents, so
+the kernel fits VMEM only at reduced geometries; `fused_vmem_bytes` is
+the estimate the capability predicate (repro.core.lowering) holds to
+`VMEM_LIMIT_BYTES` on the TPU.
 
 The head's *global* half (normalize_by_max over all pixels, dB
 compression, power-doppler's 2-D smooth) is NOT in the kernel — a
@@ -30,13 +38,13 @@ Determinism contract
 ``precision="f32"`` + interpret mode executes the *reference modules'
 own expressions* inside the kernel body: ``demod.rf_to_iq`` and
 ``doppler.apply_wall_filter`` are imported and called on the VMEM
-blocks, and the beamform uses the das_beamform one-hot-dot formulation
-(zero terms add exactly in f32; channel reduce is ONE materialized sum)
-— so the fused f32 path is bit-exact against the monolithic oracle by
-construction, asserted in tests/test_fused_pipeline.py. The compiled
-path (TPU) re-expresses both FIRs as banded weight matrices built in
-VMEM and fed to the MXU (Mosaic lowers matmuls, not conv_general) and
-is held to the same ≤1e-5 image tolerance as every other lowering.
+blocks, and the beamform uses the das_beamform channel body (zero
+one-hot terms add exactly in f32; channels accumulate in ascending
+order, the reference sum's order) — so the fused f32 path is bit-exact
+against the monolithic oracle by construction, asserted in
+tests/test_fused_pipeline.py. The compiled path (TPU) re-expresses the
+demod FIR as a banded weight matrix built in VMEM and fed to the MXU
+(Mosaic lowers matmuls, not conv_general); it has not run on the chip.
 
 ``precision="bf16"/"f16"`` casts the MATMUL OPERANDS (banded demod FIR,
 one-hot DAS weights, and their IQ counterparts) to the reduced dtype
@@ -59,152 +67,148 @@ import jax.experimental.pallas.tpu as pltpu
 # (the bit-exactness contract above). Safe import direction: repro.core
 # never imports repro.kernels at module scope.
 from repro.core import cnn_ops, demod, doppler
+from repro.kernels.das_beamform.kernel import (COMPUTE_DTYPES,
+                                               beamform_channel,
+                                               dot_precision)
+from repro.kernels.pallas_compat import (CompilerParams, VMEM_LIMIT_BYTES,
+                                         next_multiple)
 
-DEFAULT_BP = 128  # pixel-tile rows (MXU-aligned), same default as das
-
-_COMPUTE_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16,
-                   "f16": jnp.float16}
+DEFAULT_BP = 128  # pixel-tile lanes (multiple of 128 on the TPU)
 
 
-def _banded_fir(lpf, n_s: int, n_l: int, decim: int, pad_lo: int,
-                n_taps: int):
-    """The decimating SAME-padded FIR as a dense banded (n_s, n_l) matrix.
+def fused_vmem_bytes(n_l: int, n_c: int, n_f: int, decim: int) -> int:
+    """VMEM the kernel keeps resident at this geometry (f32 bytes, lane-
+    padded): the double-buffered (n_c*n_f, n_l) RF block, its two mixed
+    copies, the banded FIR and the planar IQ scratch. Pixel-tile blocks
+    are small next to these and are left out."""
+    rows = next_multiple(n_c * n_f, 8)
+    n_l_p = next_multiple(n_l, 128)
+    n_s_p = next_multiple(-(-n_l // decim), 128)
+    rf = rows * n_l_p * 4
+    return 4 * rf + n_l_p * n_s_p * 4 + 2 * rows * n_s_p * 4
 
-    W[s, l] = lpf[k] where l = s*decim + k - pad_lo. Out-of-range taps
-    simply never match — the implicit zero padding of the conv. Built in
-    VMEM per kernel invocation (n_s*n_l f32; the one-hot trick of
-    das_beamform applied to the demod FIR).
+
+def _banded_fir_t(lpf, n_l: int, n_s: int, n_s_pad: int, decim: int,
+                  pad_lo: int, n_taps: int):
+    """The decimating SAME-padded FIR as a dense banded (n_l, n_s_pad)
+    matrix.
+
+    W[l, s] = lpf[k] where l = s*decim + k - pad_lo and s < n_s.
+    Out-of-range taps simply never match — the implicit zero padding of
+    the conv — and the pad columns stay zero. Built in VMEM per kernel
+    invocation (the one-hot trick of das_beamform applied to the demod
+    FIR).
     """
-    row = lax.broadcasted_iota(jnp.int32, (n_s, n_l), 0)
-    col = lax.broadcasted_iota(jnp.int32, (n_s, n_l), 1)
-    w = jnp.zeros((n_s, n_l), dtype=jnp.float32)
+    row = lax.broadcasted_iota(jnp.int32, (n_l, n_s_pad), 0)
+    col = lax.broadcasted_iota(jnp.int32, (n_l, n_s_pad), 1)
+    w = jnp.zeros((n_l, n_s_pad), dtype=jnp.float32)
     for k in range(n_taps):       # static tap loop
-        w = w + jnp.where(col == row * decim + (k - pad_lo), lpf[k], 0.0)
+        w = w + jnp.where((row == col * decim + (k - pad_lo)) & (col < n_s),
+                          lpf[k], 0.0)
     return w
 
 
-def _demod_matmul(carrier, lpf, rf, *, decim, n_s, pad_lo, n_taps, cdt):
-    """Compiled-path demod: mix, then the banded FIR as one MXU matmul."""
-    n_l, n_c, n_f = rf.shape
-    x = rf.astype(jnp.float32)
-    mixed_re = x * carrier[:, 0][:, None, None]          # (n_l, n_c, n_f)
-    mixed_im = x * carrier[:, 1][:, None, None]
-    w = _banded_fir(lpf, n_s, n_l, decim, pad_lo, n_taps).astype(cdt)
-    out_re = jnp.dot(w, mixed_re.reshape(n_l, -1).astype(cdt),
-                     preferred_element_type=jnp.float32)
-    out_im = jnp.dot(w, mixed_im.reshape(n_l, -1).astype(cdt),
-                     preferred_element_type=jnp.float32)
-    return jnp.stack([out_re.reshape(n_s, n_c, n_f),
-                      out_im.reshape(n_s, n_c, n_f)], axis=-1)
+def _demod_matmul(carrier, lpf, rf, *, decim, n_s, n_s_pad, pad_lo,
+                  n_taps, precision):
+    """Compiled-path demod: mix, then the banded FIR as one MXU matmul.
 
-
-def _beamform_tile(idx, frac, apod, rot, iq, *, cdt):
-    """One pixel tile of the das_beamform one-hot DAS (kernel-body copy
-    operating on the scratch IQ; see das_beamform/kernel.py for the
-    bit-exactness rationale — zero one-hot terms add exactly, rot/apod
-    post-dot in the gather path's f32 expression order, channel reduce
-    as ONE materialized sum)."""
-    bp, n_c = idx.shape
-    n_s, _, n_f, _ = iq.shape
-    iota = lax.broadcasted_iota(jnp.int32, (bp, n_s), 1)
-
-    def channel_body(c, per_c):
-        per_re, per_im = per_c
-        idx_c = idx[:, c][:, None]                       # (bp, 1)
-        frac_c = frac[:, c][:, None]
-        apod_c = apod[:, c][:, None]
-        w = (jnp.where(iota == idx_c, 1.0 - frac_c, 0.0) +
-             jnp.where(iota == idx_c + 1, frac_c, 0.0))  # (bp, n_s)
-        v_re = jnp.dot(w.astype(cdt), iq[:, c, :, 0].astype(cdt),
-                       preferred_element_type=jnp.float32)
-        v_im = jnp.dot(w.astype(cdt), iq[:, c, :, 1].astype(cdt),
-                       preferred_element_type=jnp.float32)
-        rot_re = rot[:, c, 0][:, None]
-        rot_im = rot[:, c, 1][:, None]
-        per_re = lax.dynamic_update_index_in_dim(
-            per_re, (v_re * rot_re - v_im * rot_im) * apod_c, c, 0)
-        per_im = lax.dynamic_update_index_in_dim(
-            per_im, (v_re * rot_im + v_im * rot_re) * apod_c, c, 0)
-        return per_re, per_im
-
-    zero = jnp.zeros((n_c, bp, n_f), dtype=jnp.float32)
-    per_re, per_im = lax.fori_loop(0, n_c, channel_body, (zero, zero))
-    return per_re.sum(axis=0), per_im.sum(axis=0)        # 2x (bp, n_f)
+    rf (n_c*n_f, n_l), carrier (2, n_l) -> planar IQ, 2x
+    (n_c*n_f, n_s_pad).
+    """
+    cdt = COMPUTE_DTYPES[precision]
+    w = _banded_fir_t(lpf, rf.shape[1], n_s, n_s_pad, decim, pad_lo,
+                      n_taps).astype(cdt)
+    out = []
+    for r in range(2):
+        mixed = (rf * carrier[r:r + 1]).astype(cdt)
+        out.append(jnp.dot(mixed, w, precision=dot_precision(precision),
+                           preferred_element_type=jnp.float32))
+    return out
 
 
 def _wall_power_tile(wall, bf_re, bf_im, *, exact):
-    """Tile-local power-doppler front: FIR along frames -> R0 power."""
+    """Tile-local power-doppler front on planar (n_f, bp) beamformed IQ:
+    FIR along frames -> (1, bp) R0 power."""
     if exact:
         # Reference expression, verbatim (bit-exact in interpret mode).
         z = doppler.apply_wall_filter(
-            {"wall_taps": wall}, jnp.stack([bf_re, bf_im], axis=-1))
-        return cnn_ops.cabs2(z).sum(axis=1)              # (bp,)
+            {"wall_taps": wall}, jnp.stack([bf_re.T, bf_im.T], axis=-1))
+        return cnn_ops.cabs2(z).sum(axis=1)[None, :]
     kw = wall.shape[0]
-    n_fp = bf_re.shape[1] - kw + 1
-    acc_re = jnp.zeros((bf_re.shape[0], n_fp), dtype=jnp.float32)
+    n_fp = bf_re.shape[0] - kw + 1
+    acc_re = jnp.zeros((n_fp, bf_re.shape[1]), dtype=jnp.float32)
     acc_im = acc_re
     for t in range(kw):                                  # static tap loop
-        acc_re = acc_re + wall[t] * bf_re[:, t:t + n_fp]
-        acc_im = acc_im + wall[t] * bf_im[:, t:t + n_fp]
-    return (acc_re * acc_re + acc_im * acc_im).sum(axis=1)
+        acc_re = acc_re + wall[t] * bf_re[t:t + n_fp]
+        acc_im = acc_im + wall[t] * bf_im[t:t + n_fp]
+    return (acc_re * acc_re + acc_im * acc_im).sum(axis=0, keepdims=True)
 
 
-def _make_kernel(head: str, *, decim, n_s, pad_lo, n_taps, precision,
-                 exact):
-    cdt = _COMPUTE_DTYPES[precision]
+def _make_kernel(head: str, *, n_c, n_f, n_l, decim, n_s, pad_lo, n_taps,
+                 precision, exact):
 
-    def kernel(carrier_ref, lpf_ref, idx_ref, frac_ref, apod_ref, rot_ref,
-               *rest):
+    def kernel(carrier_ref, lpf_ref, idx_ref, tab_ref, *rest):
         if head == "power_doppler":
             wall_ref, rf_ref, out_ref, iq_ref = rest
         else:
             rf_ref, out_ref, iq_ref = rest
 
-        i = pl.program_id(0)
-
-        @pl.when(i == 0)
+        @pl.when(pl.program_id(0) == 0)
         def _demod_once():
             # The IQ cube is pixel-independent: computed on the first
             # grid step only, persisted in scratch across the sequential
             # steps — the HBM round trip the fusion eliminates.
             if exact:
-                iq_ref[...] = demod.rf_to_iq(
-                    {"carrier": carrier_ref[...], "lpf": lpf_ref[0]},
-                    rf_ref[...], decim)
+                rf = rf_ref[...].reshape(n_c, n_f, n_l).transpose(2, 0, 1)
+                iq = demod.rf_to_iq(
+                    {"carrier": carrier_ref[...].T, "lpf": lpf_ref[0]},
+                    rf, decim)                           # (n_s, c, f, 2)
+                iq = iq.transpose(3, 1, 2, 0).reshape(2, n_c * n_f, -1)
+                iq_ref[...] = jnp.zeros_like(iq_ref)
+                iq_ref[:, :, :iq.shape[2]] = iq
             else:
-                iq_ref[...] = _demod_matmul(
+                re, im = _demod_matmul(
                     carrier_ref[...], lpf_ref[0], rf_ref[...],
-                    decim=decim, n_s=n_s, pad_lo=pad_lo, n_taps=n_taps,
-                    cdt=cdt)
+                    decim=decim, n_s=n_s, n_s_pad=iq_ref.shape[2],
+                    pad_lo=pad_lo, n_taps=n_taps, precision=precision)
+                iq_ref[0] = re
+                iq_ref[1] = im
 
-        bf_re, bf_im = _beamform_tile(
-            idx_ref[...], frac_ref[...], apod_ref[...], rot_ref[...],
-            iq_ref[...], cdt=cdt)
+        bf_re = jnp.zeros((n_f, idx_ref.shape[2]), jnp.float32)
+        bf_im = bf_re
+        for c in range(n_c):              # ascending: the reference order
+            re, im = beamform_channel(
+                idx_ref[c], tab_ref[c], iq_ref[0, c * n_f:(c + 1) * n_f],
+                iq_ref[1, c * n_f:(c + 1) * n_f], precision=precision)
+            bf_re = bf_re + re
+            bf_im = bf_im + im
 
         if head == "bmode":
-            out_ref[...] = cnn_ops.magnitude(bf_re, bf_im)   # (bp, n_f)
+            out_ref[...] = cnn_ops.magnitude(bf_re, bf_im)   # (n_f, bp)
         else:
-            out_ref[:, 0] = _wall_power_tile(
-                wall_ref[0], bf_re, bf_im, exact=exact)      # (bp,)
+            out_ref[...] = _wall_power_tile(
+                wall_ref[0], bf_re, bf_im, exact=exact)      # (1, bp)
 
     return kernel
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("head", "decim", "bp", "precision", "interpret"))
-def fused_pipeline_pallas(carrier, lpf, idx, frac, apod, rot, rf,
-                          wall=None, *, head: str, decim: int,
+    static_argnames=("head", "n_f", "decim", "bp", "precision",
+                     "interpret"))
+def fused_pipeline_pallas(carrier, lpf, idx, tab, rf, wall=None, *,
+                          head: str, n_f: int, decim: int,
                           bp: int = DEFAULT_BP, precision: str = "f32",
                           interpret: bool = True):
-    """(n_l, n_c, n_f) RF -> (n_pix, n_f) envelope [bmode] or
-    (n_pix,) wall-filtered power R0 [power_doppler].
+    """(n_c*n_f, n_l) RF -> (n_f, n_pix) envelope [bmode] or (1, n_pix)
+    wall-filtered power R0 [power_doppler].
 
-    n_pix must be a multiple of bp (ops.py pads); lpf arrives (1, k) and
+    Tables come planar and padded from das_beamform.ops.planar_tables
+    (n_pix a multiple of bp); carrier arrives (2, n_l), lpf (1, k) and
     wall (1, kw) so every VMEM block is >= 2-D.
     """
-    n_pix, n_c = idx.shape
-    n_l = rf.shape[0]
+    n_c, _, n_pix = idx.shape
+    n_l = rf.shape[1]
     n_s = n_l // decim
     assert n_pix % bp == 0, (n_pix, bp)
     n_taps = lpf.shape[-1]
@@ -215,39 +219,38 @@ def fused_pipeline_pallas(carrier, lpf, idx, frac, apod, rot, rf,
     # the operand casts ARE the precision contract.
     exact = precision == "f32" and interpret
 
-    kernel = _make_kernel(head, decim=decim, n_s=n_s, pad_lo=pad_lo,
-                          n_taps=n_taps, precision=precision, exact=exact)
+    kernel = _make_kernel(head, n_c=n_c, n_f=n_f, n_l=n_l, decim=decim,
+                          n_s=n_s, pad_lo=pad_lo, n_taps=n_taps,
+                          precision=precision, exact=exact)
 
-    in_specs = [
-        pl.BlockSpec(carrier.shape, lambda i: (0, 0)),          # carrier
-        pl.BlockSpec(lpf.shape, lambda i: (0, 0)),              # lpf
-        pl.BlockSpec((bp, n_c), lambda i: (i, 0)),              # idx
-        pl.BlockSpec((bp, n_c), lambda i: (i, 0)),              # frac
-        pl.BlockSpec((bp, n_c), lambda i: (i, 0)),              # apod
-        pl.BlockSpec((bp, n_c, 2), lambda i: (i, 0, 0)),        # rot
-    ]
-    args = [carrier, lpf, idx, frac, apod, rot]
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda i: (0,) * a.ndim)
+
+    in_specs = [whole(carrier), whole(lpf),
+                pl.BlockSpec((n_c, 1, bp), lambda i: (0, 0, i)),
+                pl.BlockSpec((n_c, 4, bp), lambda i: (0, 0, i))]
+    args = [carrier, lpf, idx, tab]
     if head == "power_doppler":
-        in_specs.append(pl.BlockSpec(wall.shape, lambda i: (0, 0)))
+        in_specs.append(whole(wall))
         args.append(wall)
-        out_spec = pl.BlockSpec((bp, 1), lambda i: (i, 0))
-        out_shape = jax.ShapeDtypeStruct((n_pix, 1), jnp.float32)
+        rows = 1
     elif head == "bmode":
-        n_f = rf.shape[2]
-        out_spec = pl.BlockSpec((bp, n_f), lambda i: (i, 0))
-        out_shape = jax.ShapeDtypeStruct((n_pix, n_f), jnp.float32)
+        rows = n_f
     else:
         raise ValueError(f"unsupported fused head: {head!r}")
-    in_specs.append(pl.BlockSpec(rf.shape, lambda i: (0, 0, 0)))  # rf
+    in_specs.append(whole(rf))
     args.append(rf)
 
     return pl.pallas_call(
         kernel,
         grid=(n_pix // bp,),
         in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((n_s, n_c, rf.shape[2], 2),
-                                   jnp.float32)],
+        out_specs=pl.BlockSpec((rows, bp), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((rows, n_pix), jnp.float32),
+        scratch_shapes=[pltpu.VMEM(
+            (2, rf.shape[0], next_multiple(n_s, 128)), jnp.float32)],
+        compiler_params=CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*args)

@@ -1,11 +1,10 @@
-"""Compat + convenience shims shared by every Pallas kernel wrapper.
+"""Convenience shims shared by every Pallas kernel wrapper.
 
-jax<0.5 exposes TPU compiler params as ``pltpu.TPUCompilerParams``; 0.5+
-renamed it ``CompilerParams``. Resolve once here so the next rename is a
-one-line fix. `auto_interpret` is the shared interpret-mode fallback
-policy (compiled only where Mosaic runs, interpret everywhere else) and
-`next_multiple` the shared tile-padding helper — one definition each,
-so the kernels' portability contract cannot fork per package.
+`auto_interpret` is the shared interpret-mode policy (compiled where
+Mosaic runs, interpret mode for the CPU tests), `next_multiple` the
+shared tile-padding helper and `VMEM_LIMIT_BYTES` the scoped-VMEM
+budget every kernel declares — one definition each, so the kernels'
+contract cannot fork per package.
 """
 
 from __future__ import annotations
@@ -17,13 +16,21 @@ import jax.numpy as jnp
 import jax.experimental.pallas.tpu as pltpu
 
 
+CompilerParams = pltpu.CompilerParams
+
+# Scoped VMEM each compiled kernel may use. A TPU v5e core has 128 MiB of
+# VMEM and XLA's default scoped limit is 16 MiB; kernels declare this
+# budget explicitly, and the fused megakernel's capability predicate
+# refuses geometries whose resident blocks exceed it.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
 def auto_interpret(interpret: Optional[bool]) -> bool:
     """Resolve the ``interpret=None`` default of every kernel wrapper.
 
     None means "compiled where the Mosaic TPU backend exists, interpret
-    mode everywhere else" — the fallback that keeps one source tree
-    runnable on every backend (the repo's portability contract; the
-    registry's capability predicates assume it).
+    mode everywhere else". Interpret mode exists for the CPU tests; it
+    is never a measurement path.
     """
     if interpret is None:
         return jax.default_backend() != "tpu"
@@ -49,17 +56,3 @@ def block_sample_axis(iq: jnp.ndarray, bs: int) -> jnp.ndarray:
     if pad:
         iq = jnp.pad(iq, ((0, pad),) + ((0, 0),) * (iq.ndim - 1))
     return iq.reshape((-1, bs) + iq.shape[1:])
-
-
-def _resolve_compiler_params():
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:  # pragma: no cover — future rename
-        raise ImportError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams; update repro.kernels.pallas_compat "
-            "for this jax version")
-    return cls
-
-
-CompilerParams = _resolve_compiler_params()

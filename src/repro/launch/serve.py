@@ -454,6 +454,8 @@ def main() -> None:
                          "loop's critical path, block = legacy "
                          "blocking harvest; bit-identical outputs)")
     args = ap.parse_args()
+    from repro.core.aot import configure_persistent_cache
+    configure_persistent_cache()
 
     if args.variant == "auto" and args.plan == "fixed":
         ap.error("--variant auto needs --plan heuristic or autotune")

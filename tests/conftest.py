@@ -22,7 +22,7 @@ def _sandbox_compile_cache(tmp_path_factory):
     """Keep the XLA persistent compilation cache inside the session.
 
     Same discipline as the consts cache: AOT warm-ups must never
-    populate (or hit) the user's `~/.cache/repro/xla` from a test run —
+    populate (or hit) the checkout's `.jax_cache` from a test run —
     the warm-start tests assert cold-vs-warm timing differences that a
     pre-populated cache would erase.
     """

@@ -10,8 +10,9 @@ Three contracts (repro.core.aot):
   * accounting — warm-up cost is measured (`compile_s` / `warmup_s`)
     and lands in the serving stats of the window that paid it; pool
     hits charge zero and stamp ``warm_source="pool"``.
-  * warm start — the persistent compilation cache (env-configurable
-    dir, sandboxed by conftest) is actually written, and a FRESH
+  * warm start — the persistent compilation cache
+    (``JAX_COMPILATION_CACHE_DIR`` or a fixed in-checkout dir, sandboxed
+    by conftest) is actually written, and a FRESH
     process pointed at a populated cache compiles cheaper and serves
     its first dispatch without a compile spike (within 3x the
     steady-state p50) — the subprocess test at the bottom.
@@ -60,25 +61,29 @@ def test_aot_warm_bit_identical_to_jit_path():
 
 
 def test_cache_dir_env_resolution(monkeypatch, tmp_path):
-    """REPRO_COMPILE_CACHE_DIR follows the consts-cache discipline:
-    unset -> user cache dir, "" / "0" -> disabled, path -> path."""
+    """JAX_COMPILATION_CACHE_DIR wins and is the only directory set;
+    without it the cache sits at one fixed, gitignored path inside the
+    checkout (never a temp name, pid or time)."""
     import repro.core.aot as aot
 
-    sandbox = aot.compile_cache_dir()       # conftest's tmp dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.setattr(aot, "_cache_override", aot._UNSET)  # drop sandbox
     try:
-        monkeypatch.delenv("REPRO_COMPILE_CACHE_DIR", raising=False)
-        aot._cache_resolved = False
-        assert aot.compile_cache_dir().endswith(
-            os.path.join(".cache", "repro", "xla"))
-        for off in ("", "0"):
-            monkeypatch.setenv("REPRO_COMPILE_CACHE_DIR", off)
-            aot._cache_resolved = False
-            assert aot.compile_cache_dir() is None
-        monkeypatch.setenv("REPRO_COMPILE_CACHE_DIR", str(tmp_path))
-        aot._cache_resolved = False
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert aot.compile_cache_dir() == str(tmp_path)
+        assert aot.configure_persistent_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = aot.compile_cache_dir()
+        assert fixed == aot.DEFAULT_CACHE_DIR == os.path.join(repo,
+                                                              ".jax_cache")
+        assert aot.compile_cache_dir() == fixed          # stable
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
     finally:
-        aot.set_compile_cache_dir(sandbox)
+        monkeypatch.undo()
+        aot.configure_persistent_cache()     # back to the sandbox dir
 
 
 def test_persistent_cache_written_by_aot_warm():
@@ -162,7 +167,7 @@ from repro.data import synth_rf
 
 cfg = tiny_config(variant=Variant.DYNAMIC)
 eng = BatchedExecutor(cfg)
-prog = aot_warm(eng, 4)          # REPRO_COMPILE_CACHE_DIR set by the test
+prog = aot_warm(eng, 4)          # JAX_COMPILATION_CACHE_DIR set by the test
 rf = np.stack([synth_rf(cfg, seed=s) for s in range(2)])
 times = []
 for _ in range(21):
@@ -184,7 +189,7 @@ def warm_start_runs(tmp_path_factory):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
-    env["REPRO_COMPILE_CACHE_DIR"] = cache
+    env["JAX_COMPILATION_CACHE_DIR"] = cache
 
     def run():
         proc = subprocess.run(

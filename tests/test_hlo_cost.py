@@ -33,10 +33,7 @@ def test_scan_flops_multiplied_by_trip_count(scanned_module):
 
 def test_xla_cost_analysis_counts_body_once(scanned_module):
     """The reason hlo_cost exists (documented limitation of XLA)."""
-    ca = scanned_module.cost_analysis()
-    if isinstance(ca, list):        # jax<0.5 returns [dict], 0.5+ a dict
-        ca = ca[0] if ca else {}
-    xla_flops = ca.get("flops", 0.0)
+    xla_flops = scanned_module.cost_analysis().get("flops", 0.0)
     assert xla_flops < 2 * 2 * 128 ** 3   # ~one body, not ten
 
 

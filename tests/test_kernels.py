@@ -13,8 +13,8 @@ from repro.core import tiny_config
 from repro.core.delays import bsr_operator, compute_delay_tables
 from repro.kernels.das_beamform import das_beamform
 from repro.kernels.das_beamform.ref import das_beamform_ref
-from repro.kernels.bsr_spmm import bsr_beamform, bsr_spmm
-from repro.kernels.bsr_spmm.ref import bsr_spmm_ref
+from repro.kernels.bsr_spmm import bsr_beamform
+from repro.kernels.bsr_spmm.ref import bsr_beamform_ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.ssd_scan import ssd_scan
@@ -62,7 +62,7 @@ def test_das_beamform_matches_pipeline_tables(rng):
 
 
 # ---------------------------------------------------------------------------
-# bsr_spmm
+# bsr_beamform
 # ---------------------------------------------------------------------------
 
 
@@ -72,13 +72,14 @@ def test_das_beamform_matches_pipeline_tables(rng):
     (3, 3, 32, 8, 9, 1),
 ])
 def test_bsr_spmm_sweep(rng, n_pb, K, bp, bs, n_sb, nf):
-    cols = rng.integers(0, n_sb, (n_pb, K)).astype(np.int32)
-    blocks = rng.standard_normal((n_pb, K, bp, bs)).astype(np.float32)
-    x = rng.standard_normal((n_sb, bs, nf)).astype(np.float32)
-    out = bsr_spmm(jnp.asarray(cols), jnp.asarray(blocks), jnp.asarray(x))
-    ref = bsr_spmm_ref(jnp.asarray(cols), jnp.asarray(blocks),
-                       jnp.asarray(x))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    n_c = 3     # channels accumulate in the grid alongside stored blocks
+    cols = rng.integers(0, n_sb, (n_c, n_pb, K)).astype(np.int32)
+    blocks = rng.standard_normal((n_c, n_pb, K, bp, 2 * bs)).astype(
+        np.float32)
+    iq_b = rng.standard_normal((n_sb, bs, n_c, nf, 2)).astype(np.float32)
+    args = tuple(jnp.asarray(a) for a in (cols, blocks, iq_b))
+    np.testing.assert_allclose(np.asarray(bsr_beamform(*args)),
+                               np.asarray(bsr_beamform_ref(*args)),
                                rtol=2e-4, atol=2e-4)
 
 

@@ -130,7 +130,12 @@ def test_all_policies_allclose_to_monolithic_oracle(policy):
     measure = (lambda c, v, *, runs, warmup:
                {Variant.DYNAMIC: 1.0, Variant.CNN: 2.0,
                 Variant.SPARSE: 3.0}[v])
-    plan = plan_pipeline(cfg, policy=policy, measure=measure)
+    # The stage probe is pinned too: left to wall time, the lowering
+    # choice (and so the summation order under test) would vary per run.
+    measure_stage = (lambda c, s, *, runs, warmup:
+                     {"xla": 1.0, "pallas": 2.0}[dict(c.stage_lowerings)[s]])
+    plan = plan_pipeline(cfg, policy=policy, measure=measure,
+                         measure_stage=measure_stage)
     pipe = UltrasoundPipeline(cfg, plan=plan)
     assert pipe.cfg.variant.concrete
 

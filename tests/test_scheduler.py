@@ -544,7 +544,7 @@ def sharded_results(tmp_path_factory):
         os.path.join(os.path.dirname(__file__), "..", "src"))
     # Sandbox the subprocess's persistent compile cache like conftest
     # does in-process (AOT warm-up must not touch the user cache dir).
-    env["REPRO_COMPILE_CACHE_DIR"] = str(
+    env["JAX_COMPILATION_CACHE_DIR"] = str(
         tmp_path_factory.mktemp("subproc-xla-cache"))
     proc = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
                           capture_output=True, text=True, timeout=600)
