@@ -23,12 +23,17 @@ its executors are released before the next window starts: the CNN
 operator alone is 2.8 GB per group, and five groups' operators plus
 their temporaries do not fit one chip's 16 GB of HBM together.
 
-Every served image is compared with `monolithic_pipeline_fn` run on the
-same chip under ``jax.default_matmul_precision("highest")``, within the
-per-modality tolerances in `TOLERANCES`. With ``--chips 4`` the same
-streams are served on ``jax.local_devices()[:4]`` and compared with a
-one-chip window of the same frames, and every device must hold its own
-batch shard and report its own non-zero peak memory.
+Every served image is compared, within the per-modality tolerances in
+`TOLERANCES`, with two references run on the same chip under
+``jax.default_matmul_precision("highest")``: `monolithic_pipeline_fn` of
+the group's own variant and constants, and `monolithic_pipeline_fn` of
+the dynamic variant (the explicit gather on its own small delay tables),
+which shares no operator with the CNN or sparse groups. Each window must
+leave its operators resident on the device: bytes in use grow over the
+window's starting baseline. With ``--chips 4`` the same streams are
+served on ``jax.local_devices()[:4]`` and compared with a one-chip
+window of the same frames; every device must hold its own batch shard,
+and its bytes in use must grow over its own baseline.
 
 The last line of standard output is the result:
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -161,72 +166,116 @@ def check_plan(engine, variant: str, lowerings: dict) -> bool:
             and plan.backend == "tpu")
 
 
-def check_memory(stats) -> bool:
+def bytes_in_use(devices) -> list:
+    """Allocator bytes in use per device (not a peak)."""
+    from repro.bench.resources import device_memory_stats_list
+    stats = device_memory_stats_list(devices)
+    if stats is None:
+        raise RuntimeError("device memory statistics are unavailable")
+    return [u for _, u in stats]
+
+
+def check_memory(stats, devices, baseline) -> bool:
+    """The scheduler's meter reports a number, and the window still
+    holds memory of its own on every device.
+
+    The meter's ``peak_memory_bytes`` is the allocator's process-lifetime
+    peak only where the window set a new one (``device_memory_stats``);
+    after the first CNN window it is bytes in use sampled during the
+    window (``device_bytes_in_use``, a lower bound). Bytes in use now,
+    with the window's executors alive, against the window's starting
+    baseline say what this window itself holds on each device.
+    """
     res = stats["resources"]
+    held = [u - b for u, b in zip(bytes_in_use(devices), baseline)]
     _say(peak_memory_bytes=res["peak_memory_bytes"],
          memory_source=res["memory_source"],
+         held_bytes_per_device=json.dumps(held),
          energy="not measured" if res["energy_joules"] is None
          else res["energy_joules"])
     return (res["peak_memory_bytes"] is not None
             and res["memory_source"] in ("device_memory_stats",
                                          "device_bytes_in_use")
+            and all(h > 0 for h in held)
             and res["energy_joules"] is None)
 
 
-def reference_images(spec, engine) -> list:
-    """`monolithic_pipeline_fn` at the highest matmul precision, one
-    image per distinct RF of the stream's pool."""
+def reference_images(spec, cfg, consts) -> list:
+    """`monolithic_pipeline_fn` of ``cfg`` at the highest matmul
+    precision, one image per distinct RF of the stream's pool."""
     import jax
     import jax.numpy as jnp
     from repro.core.pipeline import monolithic_pipeline_fn
     from repro.data import synth_rf
-    fn = jax.jit(monolithic_pipeline_fn(engine.cfg))
+    fn = jax.jit(monolithic_pipeline_fn(cfg))
     images = []
     with jax.default_matmul_precision("highest"):
         for i in range(min(POOL, FRAMES)):
             rf = jnp.asarray(synth_rf(spec.cfg, seed=spec.frame_seed(i)))
-            images.append(np.asarray(fn(engine.consts, rf)))
+            images.append(np.asarray(fn(consts, rf)))
     return images
 
 
-def compare_stream(modality, outputs, references, smooth_kernel) -> bool:
+def dynamic_reference_images(spec, engine) -> list:
+    """The same frames through the dynamic variant's XLA gather, built
+    from its own delay tables: independent of the CNN operator and of the
+    sparse blocks."""
+    from repro.core.config import Variant
+    from repro.core.pipeline import init_pipeline
+    if engine.cfg.variant == Variant.DYNAMIC:
+        cfg = engine.cfg
+    else:
+        cfg = engine.cfg.with_(variant=Variant.DYNAMIC, stage_lowerings=())
+    return reference_images(spec, cfg, init_pipeline(cfg))
+
+
+def compare_stream(modality, outputs, references, smooth_kernel, *,
+                   against: str) -> bool:
     """Compare every served image; report the worst (a failing one
     first)."""
     cs = [compare(modality, got, references[k % len(references)],
                   smooth_kernel) for k, got in enumerate(outputs)]
     worst = max(cs, key=lambda c: (not c["ok"], c["max_abs"]))
-    _say(tolerance=TOLERANCES[modality], compared=len(cs),
+    _say(against=against, tolerance=TOLERANCES[modality], compared=len(cs),
          **{k: v for k, v in worst.items() if k != "ok"},
          within=worst["ok"])
     return all(c["ok"] for c in cs)
 
 
 def smoke_one_chip(base) -> bool:
+    import jax
+    devices = jax.devices()[:1]
     ok = True
     for i, (name, cfg, variant, lowerings) in enumerate(tenant_groups(base)):
         print(f"group {name}: window {i + 1} of 5", flush=True)
         t0 = time.perf_counter()
+        baseline = bytes_in_use(devices)
         spec, entry, stats = serve_window(name, cfg, seed=i)
         engine, prog = entry.engine, entry.program
         checks = {
             "plan": check_plan(engine, variant, lowerings),
             "kernel": check_tpu_program(lowerings, prog),
-            "memory": check_memory(stats),
+            "memory": check_memory(stats, devices, baseline),
         }
         _say(compile_s=f"{prog.compile_s:.3f}",
              warmup_s=f"{prog.warmup_s:.3f}", cache_dir=prog.cache_dir,
              acquisitions=stats["acquisitions"],
              batches=sum(g["batches"] for g in stats["groups"].values()))
         checks["served"] = stats["acquisitions"] == FRAMES
-        refs = reference_images(spec, engine)
+        outputs = stats["outputs"][name]
+        refs = reference_images(spec, engine.cfg, engine.consts)
         checks["reference"] = compare_stream(
-            cfg.modality.value, stats["outputs"][name], refs,
-            cfg.smooth_kernel)
+            cfg.modality.value, outputs, refs, cfg.smooth_kernel,
+            against=f"monolithic-{engine.cfg.variant.value}")
+        refs = dynamic_reference_images(spec, engine)
+        checks["dynamic_reference"] = compare_stream(
+            cfg.modality.value, outputs, refs, cfg.smooth_kernel,
+            against="monolithic-dynamic")
         failed = sorted(k for k, v in checks.items() if not v)
         _say(group_s=f"{time.perf_counter() - t0:.1f}",
              result="ok" if not failed else f"FAILED {failed}")
         ok = ok and not failed
-        del spec, entry, engine, prog, stats, refs   # free the window's HBM
+        del spec, entry, engine, prog, stats, outputs, refs   # free HBM
         gc.collect()
     return ok
 
@@ -234,33 +283,29 @@ def smoke_one_chip(base) -> bool:
 def smoke_four_chips(base) -> bool:
     """Batch-sharded serving on four chips vs one chip, same frames."""
     import jax
-    from repro.bench.resources import device_memory_stats_list
     devices = jax.local_devices()[:4]
     ok = True
     for i, (name, cfg, variant, lowerings) in enumerate(tenant_groups(base)):
         print(f"group {name}: 4 chips vs 1 chip", flush=True)
+        baseline = bytes_in_use(devices)
         _, entry, sharded = serve_window(name, cfg, seed=i, devices=devices)
         engine = entry.engine
         checks = {"plan": check_plan(engine, variant, lowerings),
-                  "kernel": check_tpu_program(lowerings, entry.program),
-                  "memory": check_memory(sharded)}
+                  "kernel": check_tpu_program(lowerings, entry.program)}
         _say(compile_s=f"{entry.program.compile_s:.3f}",
              devices=engine.n_devices,
              acquisitions=sharded["acquisitions"])
-        # One padded batch through the sharded program: each device
-        # must hold its own row of the batch and of the output.
+        # One padded batch through the sharded program, its output held:
+        # each device must hold its own row of the batch and of the
+        # output, and memory of its own beyond the window's baseline.
         batch = np.zeros((MAX_BATCH,) + cfg.rf_shape, cfg.rf_dtype)
         out = jax.block_until_ready(engine.dispatch_padded(batch, MAX_BATCH))
         shards = {s.device: s.data.shape[0] for s in out.addressable_shards}
-        mem = device_memory_stats_list(devices)
-        _say(shard_rows=json.dumps([shards.get(d) for d in devices]),
-             peak_bytes_per_device=json.dumps(
-                 [p for p, _ in mem] if mem else None))
+        _say(shard_rows=json.dumps([shards.get(d) for d in devices]))
+        checks["memory"] = check_memory(sharded, devices, baseline)
         checks["shards"] = (set(shards) == set(devices)
                             and all(n == MAX_BATCH // 4
                                     for n in shards.values()))
-        checks["per_device_memory"] = bool(mem and all(p > 0 and u > 0
-                                                       for p, u in mem))
         del entry, engine, out
         gc.collect()
         # The one-chip run of the same frames dispatches them one at a
@@ -271,7 +316,7 @@ def smoke_four_chips(base) -> bool:
         _say(vs_one_chip="bit-identical" if identical else "differs")
         checks["vs_one_chip"] = compare_stream(
             cfg.modality.value, sharded["outputs"][name],
-            single["outputs"][name], cfg.smooth_kernel)
+            single["outputs"][name], cfg.smooth_kernel, against="one-chip")
         failed = sorted(k for k, v in checks.items() if not v)
         _say(result="ok" if not failed else f"FAILED {failed}")
         ok = ok and not failed

@@ -570,12 +570,18 @@ def _fusion_block_timings(cfg: UltrasoundConfig, backend: str, fused, *,
     megakernel's pixel-tile candidates — the per-stage autotune memo
     generalized to a fusion group. The probe times the group entry of
     the bench_stages breakdown (stage_fns exposes the span under its
-    group key), memoized per geometry like the per-stage search."""
+    group key), memoized per geometry like the per-stage search. Only
+    the candidates the fused lowering's capability predicate admits on
+    this backend are probed (the TPU takes whole 128-lane tiles only)."""
+    from repro.core import lowering as lowering_lib
     from repro.kernels.pallas_compat import next_multiple
 
     n_pix = cfg.nz * cfg.nx
     cap = next_multiple(n_pix, 8)
-    bps = tuple(sorted({min(bp, cap) for bp in FUSION_BLOCK_CANDIDATES}))
+    bps = tuple(sorted(
+        bp for bp in {min(bp, cap) for bp in FUSION_BLOCK_CANDIDATES}
+        if lowering_lib.fused_supported(cfg.with_(fusion_block=bp),
+                                        backend)))
     memo_key = (config_hash(cfg, exclude=("exec_map", "stage_lowerings",
                                           "fusion_block")),
                 cfg.stage_lowerings, fused.group, bps, backend, runs,

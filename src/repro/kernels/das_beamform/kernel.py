@@ -16,7 +16,8 @@ Layout (planar re/im, pixels on the lane axis — no size-2 minor axis):
 
 Tiling:
   grid = (n_pix // bp, n_c // cc)   pixel tiles "parallel", channel
-                                    chunks "arbitrary" (sequential)
+                                    chunks "arbitrary" (sequential);
+                                    cc = min(CHANNEL_CHUNK, n_c)
   VMEM per step: one (cc, ., bp) slab of each table, one (2, cc, n_f, n_s)
   slab of IQ, the resident (2, n_f, bp) output tile, and one (n_s, bp)
   weight tile built per channel.
@@ -38,8 +39,8 @@ import jax.experimental.pallas as pl
 
 from repro.kernels.pallas_compat import CompilerParams, VMEM_LIMIT_BYTES
 
-DEFAULT_BP = 256   # pixel-tile lanes (multiple of 128 on the TPU)
-DEFAULT_CC = 8     # channels per grid step
+DEFAULT_BP = 128     # pixel-tile lanes (multiple of 128 on the TPU)
+CHANNEL_CHUNK = 8    # channels per grid step
 
 # Matmul-operand compute dtypes for the mixed-precision contract: only the
 # one-hot weight tile and the IQ operand are cast; accumulation stays f32
@@ -106,19 +107,20 @@ def _kernel(idx_ref, tab_ref, iq_ref, out_ref, *, precision):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bp", "cc", "precision", "interpret"))
+                   static_argnames=("bp", "precision", "interpret"))
 def das_beamform_pallas(idx, tab, iq, *, bp: int = DEFAULT_BP,
-                        cc: int = DEFAULT_CC, precision: str = "f32",
-                        interpret: bool = True):
+                        precision: str = "f32", interpret: bool = True):
     """Planar tables + IQ -> (2, n_f, n_pix) planar beamformed IQ.
 
     idx (n_c, 1, n_pix), tab (n_c, 4, n_pix), iq (2, n_c, n_f, n_s); n_pix
-    must be a multiple of bp and n_c of cc (ops.py pads both). `precision`
+    must be a multiple of bp and n_c of ``min(CHANNEL_CHUNK, n_c)`` (ops.py
+    pads both). `precision`
     selects the matmul-operand dtype (f32 | bf16 | f16); accumulation is
     always f32.
     """
     n_c, _, n_pix = idx.shape
     _, _, n_f, n_s = iq.shape
+    cc = min(CHANNEL_CHUNK, n_c)
     assert n_pix % bp == 0 and n_c % cc == 0, (n_pix, bp, n_c, cc)
     return pl.pallas_call(
         functools.partial(_kernel, precision=precision),

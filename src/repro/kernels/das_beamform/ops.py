@@ -41,8 +41,7 @@ def planar_iq(iq, n_c: int):
 
 
 def das_beamform(idx, frac, apod, rot, iq, *, bp: int = _k.DEFAULT_BP,
-                 cc: int = _k.DEFAULT_CC, precision: str = "f32",
-                 interpret=None):
+                 precision: str = "f32", interpret=None):
     """Fused delay-and-sum beamform.
 
     Args:
@@ -51,15 +50,16 @@ def das_beamform(idx, frac, apod, rot, iq, *, bp: int = _k.DEFAULT_BP,
       apod: (n_pix, n_c) f32 apodization (0 disables a (pixel, channel)).
       rot:  (n_pix, n_c, 2) f32 unit phasors.
       iq:   (n_s, n_c, n_f, 2) f32.
-      bp / cc: pixel-tile lanes / channels per grid step.
+      bp:   pixel-tile lanes.
       precision: matmul-operand dtype, "f32" | "bf16" | "f16"
         (accumulation is always f32).
     Returns:
       (n_pix, n_f, 2) f32 beamformed IQ.
     """
     n_pix = idx.shape[0]
-    idx_t, tab, bp, cc = planar_tables(idx, frac, apod, rot, bp=bp, cc=cc)
+    idx_t, tab, bp, _ = planar_tables(idx, frac, apod, rot, bp=bp,
+                                      cc=_k.CHANNEL_CHUNK)
     out = _k.das_beamform_pallas(
-        idx_t, tab, planar_iq(iq, idx_t.shape[0]), bp=bp, cc=cc,
+        idx_t, tab, planar_iq(iq, idx_t.shape[0]), bp=bp,
         precision=precision, interpret=auto_interpret(interpret))
     return out[:, :, :n_pix].transpose(2, 1, 0)

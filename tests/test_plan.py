@@ -23,7 +23,7 @@ from repro.bench import bench_callable
 from repro.core import (CONSTS_CACHE_STATS, Modality, UltrasoundPipeline,
                         Variant, clear_consts_cache, config_hash,
                         init_pipeline, monolithic_pipeline_fn, plan_pipeline,
-                        set_consts_cache_dir, tiny_config)
+                        paper_config, set_consts_cache_dir, tiny_config)
 from repro.core import plan as plan_lib
 from repro.data import synth_rf
 from repro.launch.serve import serve_ultrasound_stream
@@ -110,6 +110,28 @@ def test_autotune_picks_argmin_of_injected_timings_and_memoizes():
     plan_pipeline(cfg, policy="autotune", measure=fake_measure,
                   autotune_runs=5)
     assert len(calls) == 9
+
+
+def test_fused_block_autotune_probes_only_tiles_the_backend_admits(
+        monkeypatch):
+    """On the TPU the fused lowering takes whole 128-lane pixel tiles
+    only: autotune must not probe (or pick) a block the predicate, and so
+    resolve_fused, would refuse — even when that block times fastest."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    probed = []
+
+    def measure_stage(c, stage, *, runs, warmup):
+        probed.append(c.fusion_block)
+        return c.fusion_block / 1000.0          # smaller block = faster
+
+    cfg = paper_config(variant=Variant.DYNAMIC, fusion="fused",
+                       n_c=8, n_f=8)
+    plan = plan_pipeline(cfg, policy="autotune", measure_stage=measure_stage)
+    assert probed == [128, 256]
+    assert plan.backend == "tpu" and plan.fusion_block == 128
+    assert [k for k, _ in plan.lowering_t_s] == [
+        f"{plan.fusion_group}:pallas@bp128",
+        f"{plan.fusion_group}:pallas@bp256"]
 
 
 def test_autotune_real_timings_on_cpu_pick_fastest_variant():
