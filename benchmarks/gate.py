@@ -29,12 +29,7 @@ Two row families are gated:
     entirely below ``1/factor``. Gating acq/s per in-flight depth
     keeps the async scheduler's overlap win (depth 2 > depth 1 in the
     baseline) from regressing back to synchronous throughput
-    unnoticed. ``device_busy_frac`` and ``overlap_frac`` are gated the
-    same way (their own CI blocks, higher is better) so the overlap
-    machinery itself cannot silently decay while acq/s hides it behind
-    arrival-rate slack; a baseline cell whose metric has a zero run
-    mean (a legitimately synchronous depth-1 cell) is skipped for that
-    metric — the ratio is undefined there, not regressed.
+    unnoticed.
 
 A baseline row with no current counterpart fails loudly (a renamed or
 dropped row is a silent gate hole); extra current rows are ignored so
@@ -185,20 +180,10 @@ def gate_table1(baseline: List[dict], current: List[dict], *,
     return failures
 
 
-# Overlap-telemetry metrics gated alongside acq/s: each is
-# throughput-like (higher is better), each carries its own bootstrap CI
-# block. A baseline cell with any zero run mean is skipped for that
-# metric — the ratio is undefined, and a legitimately synchronous cell
-# (depth-1 overlap_frac == 0) must not wedge the gate.
-_MT_FRAC_METRICS = (("device_busy_frac", "device_busy_frac_ci"),
-                    ("overlap_frac", "overlap_frac_ci"))
-
-
 def gate_multitenant(baseline: List[dict], current: List[dict], *,
                      factor: float) -> List[str]:
-    """Failures: multitenant cells whose acq/s — or overlap-telemetry
-    (device_busy_frac / overlap_frac) — ratio CI excludes the allowed
-    floor."""
+    """Failures: multitenant cells whose acq/s ratio CI excludes the
+    allowed floor."""
     failures: List[str] = []
     cur: Dict[MtKey, dict] = {}
     for rec in current:
@@ -229,26 +214,6 @@ def gate_multitenant(baseline: List[dict], current: List[dict], *,
             failures.append(
                 f"multitenant cell [{cell}]: acq_per_s "
                 f"{dec.reason}{note}")
-        for metric, ci_key in _MT_FRAC_METRICS:
-            if metric not in base:
-                continue    # pre-telemetry baseline: nothing to hold
-            try:
-                base_runs, base_real = _metric_runs(
-                    base, metric, ci_key, "multitenant")
-                if any(b == 0.0 for b in base_runs):
-                    continue    # ratio undefined (synchronous cell)
-                cur_runs, cur_real = _metric_runs(
-                    row, metric, ci_key, "multitenant")
-                dec = gate_ratio(base_runs, cur_runs, factor=factor,
-                                 higher_is_better=True)
-            except GateRecordError as e:
-                failures.append(str(e))
-                continue
-            if not dec.ok:
-                note = "" if (base_real and cur_real) else " (mean-only)"
-                failures.append(
-                    f"multitenant cell [{cell}]: {metric} "
-                    f"{dec.reason}{note}")
     return failures
 
 

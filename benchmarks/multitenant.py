@@ -14,8 +14,8 @@ dispatch-on-arrival (best latency, no amortization), larger
 ``max_batch`` with a ``max_queue_delay_ms`` bound buys occupancy with
 bounded waiting. ``--in-flight`` adds the pipelining axis: depth 1 is
 the synchronous launch-block-retire loop, depth >= 2 overlaps host
-coalescing with device execution (the record's ``device_busy_frac`` /
-``overlap_frac`` columns show where the win comes from). Determinism
+coalescing with device execution (device time itself is the profiler's:
+docs/serving.md#tracing-a-serving-window). Determinism
 is not on any axis — the scheduler oracle test pins every cell's
 outputs to the per-frame monolithic reference bit-for-bit at every
 depth.
@@ -84,15 +84,14 @@ def run(client_counts: Sequence[int] = DEFAULT_CLIENTS,
     ``base_fps`` sets the fastest tenant's open-loop arrival rate —
     raise it far above the service rate to measure the device-bound
     throughput ceiling (where the in-flight overlap win is visible in
-    ``acq_per_s`` rather than only in ``device_busy_frac``).
+    ``acq_per_s``).
 
     ``repeats`` serves each cell's window that many times (the shared
     `WarmPool` means only the first window anywhere pays AOT cost) and
-    replaces the record's degenerate ``acq_per_s_ci`` /
-    ``device_busy_frac_ci`` / ``overlap_frac_ci`` with two-level
-    bootstrap CIs over the per-window values — the intervals the
-    statistical regression gate compares. The point metrics then
-    report the across-window means; the distribution blocks (latency,
+    replaces the record's degenerate ``acq_per_s_ci`` with a two-level
+    bootstrap CI over the per-window values — the interval the
+    statistical regression gate compares. ``acq_per_s`` then reports
+    the across-window mean; the distribution blocks (latency,
     occupancy) stay those of the last window.
 
     ``drains`` sweeps the host-transfer retirement mode
@@ -174,13 +173,10 @@ def run(client_counts: Sequence[int] = DEFAULT_CLIENTS,
                             for _ in range(repeats)]
                         stats = windows[-1]
                         if repeats > 1:
-                            for metric in ("acq_per_s",
-                                           "device_busy_frac",
-                                           "overlap_frac"):
-                                ci = bootstrap_ci(
-                                    [w[metric] for w in windows])
-                                stats[metric] = ci.mean
-                                stats[metric + "_ci"] = ci.json_dict()
+                            ci = bootstrap_ci(
+                                [w["acq_per_s"] for w in windows])
+                            stats["acq_per_s"] = ci.mean
+                            stats["acq_per_s_ci"] = ci.json_dict()
                         rec = {"kind": "multitenant", **stats}
                         records.append(rec)
                         lat, occ = stats["latency"], stats["occupancy"]
@@ -200,8 +196,6 @@ def run(client_counts: Sequence[int] = DEFAULT_CLIENTS,
                             f"p50_ms={lat['p50_s'] * 1e3:.2f};"
                             f"worst_stream_p95_ms={worst_p95 * 1e3:.2f};"
                             f"fill={occ['mean_fill']:.2f};"
-                            f"busy={stats['device_busy_frac']:.2f};"
-                            f"overlap={stats['overlap_frac']:.2f};"
                             f"xfer={stats['transfer_frac']:.2f};"
                             f"dropped={stats['dropped']};"
                             f"miss_rate={stats['deadline_miss_rate']:.3f}")

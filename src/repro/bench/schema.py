@@ -170,11 +170,6 @@ RECORD_KEYS: Dict[str, Dict[str, str]] = {
         "acquisitions": "int", "frames": "int",
         "sustained_mbps": "real", "fps": "real", "acq_per_s": "real",
         "acq_per_s_ci": "dict", "deadline_miss_rate": "real",
-        "device_busy_s": "real", "device_busy_frac": "real",
-        "overlap_frac": "real",
-        # Overlap-column intervals (degenerate without --repeats) so
-        # the gate can apply CI-exclusion beyond acq_per_s.
-        "device_busy_frac_ci": "dict", "overlap_frac_ci": "dict",
         # Host-transfer telemetry + the drain mode that produced it
         # ("async" = copy_to_host_async at retirement detection,
         # "block" = synchronous D2H — part of the gate cell identity).
@@ -310,9 +305,6 @@ def validate_record(rec: dict, path: str = "record") -> str:
     if kind == "multitenant":
         _check(rec["policy"], MT_POLICY_KEYS, f"{path}.policy")
         _check_ci(rec["acq_per_s_ci"], f"{path}.acq_per_s_ci")
-        _check_ci(rec["device_busy_frac_ci"],
-                  f"{path}.device_busy_frac_ci")
-        _check_ci(rec["overlap_frac_ci"], f"{path}.overlap_frac_ci")
         _check(rec["in_flight_occupancy"], INFLIGHT_KEYS,
                f"{path}.in_flight_occupancy")
         if rec["drain"] not in ("async", "block"):
@@ -325,12 +317,10 @@ def validate_record(rec: dict, path: str = "record") -> str:
             raise SchemaError(
                 f"{path}.trace_sha256: expected 64 lowercase hex chars "
                 f"(a repro-trace-v1 provenance hash), got {sha!r}")
-        for frac in ("device_busy_frac", "overlap_frac",
-                     "transfer_frac"):
-            if not 0.0 <= rec[frac] <= 1.0:
-                raise SchemaError(
-                    f"{path}.{frac}: expected a fraction in [0, 1], "
-                    f"got {rec[frac]!r}")
+        if not 0.0 <= rec["transfer_frac"] <= 1.0:
+            raise SchemaError(
+                f"{path}.transfer_frac: expected a fraction in [0, 1], "
+                f"got {rec['transfer_frac']!r}")
         if not rec["per_stream"]:
             raise SchemaError(f"{path}.per_stream: empty")
         for sid, s in rec["per_stream"].items():

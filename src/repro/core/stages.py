@@ -25,6 +25,12 @@ operator-lowering registry (repro.core.lowering): the lowering named in
 ``cfg.stage_lowerings`` (plan-resolved) executes; stages left
 unspecified run the ``xla`` reference formulation.
 
+`graph_fn` runs each stage under ``jax.named_scope(stage.name)`` (a
+fused span under its fusion group), so every operation of the compiled
+program carries its stage in its ``op_name`` metadata and a profiler
+trace can attribute device time to ``demod``, ``beamform`` and the
+head. The scopes change only metadata, never the computation.
+
 When ``cfg.fusion == "fused"`` the composition routes the registered
 fused lowering's stage SPAN through its single apply (the megakernel)
 and composes the remaining stages around it; ``stage_fns`` then exposes
@@ -40,6 +46,7 @@ from typing import Callable, Dict, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import delays, demod, doppler, lowering
@@ -138,7 +145,6 @@ def _fused_span(cfg: UltrasoundConfig):
     composing per-stage."""
     if cfg.fusion != "fused":
         return None
-    import jax
     return lowering.resolve_fused(cfg, jax.default_backend())
 
 
@@ -159,7 +165,8 @@ def graph_fn(cfg: UltrasoundConfig) -> Callable:
         def run(consts, rf):
             x = rf
             for stage in stages:
-                x = stage.apply(cfg, consts, x)
+                with jax.named_scope(stage.name):
+                    x = stage.apply(cfg, consts, x)
             return x
         return run
 
@@ -168,10 +175,13 @@ def graph_fn(cfg: UltrasoundConfig) -> Callable:
     def run_fused(consts, rf):
         x = rf
         for stage in prefix:
-            x = stage.apply(cfg, consts, x)
-        x = fused.apply(cfg, consts, x)
+            with jax.named_scope(stage.name):
+                x = stage.apply(cfg, consts, x)
+        with jax.named_scope(fused.group):
+            x = fused.apply(cfg, consts, x)
         for stage in suffix:
-            x = stage.apply(cfg, consts, x)
+            with jax.named_scope(stage.name):
+                x = stage.apply(cfg, consts, x)
         return x
 
     return run_fused

@@ -70,12 +70,19 @@ Telemetry per window (stamped into the established NDJSON records by
 and completion latency (done − arrival) distributions, aggregate and
 per-stream (LatencyStats: p50/p95/p99, jitter, deadline-miss rate
 against each stream's own budget), per-dispatch batch occupancy
-(`OccupancyStats`: mean fill, full-batch rate), device-overlap columns
-(``device_busy_frac``, ``overlap_frac``, `InFlightStats` of the ring),
-per-group resolved `PipelinePlan` stamps (serving context included:
+(`OccupancyStats`: mean fill, full-batch rate), `InFlightStats` of the
+ring, per-group resolved `PipelinePlan` stamps (serving context included:
 warm_start, in_flight), warm-up seconds total and per group, and the
 `ResourceStats` of the window (sampled at drain time, so peak-memory
 telemetry sees overlapped batches live together).
+
+Device time is not measured here but by the profiler, on the clock of
+the device trace: every launched batch leaves ``serve.*`` spans
+(`jax.profiler.TraceAnnotation`, carrying the batch's launch index
+``idx`` and its group key ``group``) around the loop's steps, and the
+compiled program names each stage (`repro.core.stages.graph_fn`). With
+no trace running a span costs under a microsecond of host time.
+docs/serving.md#tracing-a-serving-window lists them.
 
 Invariants (asserted in tests/test_scheduler.py):
 
@@ -106,6 +113,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.config import UltrasoundConfig
 from repro.core.staging import StagingRing
@@ -334,6 +342,7 @@ class _Pending:
     batch: List[_Frame]
     out: object                # device array, possibly still computing
     t_dispatch: float
+    idx: int                   # launch index (position in dispatch_order)
 
 
 def _ready(out) -> bool:
@@ -596,8 +605,7 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
     Returns a stats dict (schema: `repro.bench.schema`, kind
     "multitenant" once the benchmark stamps it): aggregate + per-stream
     latency and queue-delay LatencyStats, OccupancyStats,
-    device-overlap columns (``device_busy_frac``, ``overlap_frac``,
-    ``in_flight_occupancy``), warm-up seconds, per-group plan stamps,
+    ``in_flight_occupancy``, warm-up seconds, per-group plan stamps,
     ResourceStats, sustained MB/s / FPS / acq/s. Load provenance is
     stamped on every window: ``load_profile`` (the scenario name —
     part of the gate's cell identity), ``trace_sha256`` (the
@@ -668,21 +676,13 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
     outputs: Dict[str, dict] = {s.stream_id: {} for s in specs}
     delay_s = policy.max_queue_delay_ms / 1e3
 
-    # In-flight ring + host-observed device-busy accounting. The busy
-    # clock runs whenever >= 1 dispatch is pending; sleeps taken while
-    # it runs are subtracted to get the fraction of the wall the host
-    # spent doing USEFUL work (admit/coalesce/launch/drain) concurrent
-    # with device execution. ``landing`` holds batches whose COMPUTE is
-    # known settled (they no longer occupy the in-flight ring) but
-    # whose images are still crossing D2H (async drain) — transfer
-    # time, not device-busy time.
+    # In-flight ring. ``landing`` holds batches whose COMPUTE is known
+    # settled (they no longer occupy the in-flight ring) but whose
+    # images are still crossing D2H (async drain).
     pending: collections.deque = collections.deque()
     landing: collections.deque = collections.deque()
     dispatch_order: List[List[List[object]]] = []   # [[stream_id, seq]]
     depth_samples: List[int] = []
-    busy_since: Optional[float] = None
-    device_busy_s = 0.0
-    sleep_while_busy_s = 0.0
     h2d_s = 0.0               # timed `place` (host buffer -> device)
     d2h_s = 0.0               # residual wait for images to land on host
     poll_base = _poll_base()
@@ -703,9 +703,10 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
         includes the transfer, exactly as the blocking drain counts it.
         """
         nonlocal d2h_s
-        t = time.perf_counter()
-        out = np.asarray(p.out)
-        d2h_s += time.perf_counter() - t
+        with TraceAnnotation("serve.harvest", idx=p.idx, group=p.group.key):
+            t = time.perf_counter()
+            out = np.asarray(p.out)
+            d2h_s += time.perf_counter() - t
         t_done = clk()
         p.group.n_pending -= 1
         p.group.occupancies.append(len(p.batch))
@@ -734,7 +735,6 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
         Harvests run in detection order — group-FIFO is preserved
         end to end.
         """
-        nonlocal busy_since, device_busy_s
         retired = 0
         while landing:
             retired += harvest(landing.popleft())
@@ -749,6 +749,9 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
                 jax.block_until_ready(p.out)
             meter.sample()     # detection: overlapped batches are live
             pending.remove(p)
+            with TraceAnnotation("serve.detect", idx=p.idx,
+                                 group=p.group.key):
+                pass
             if drain == "block":
                 retired += harvest(p)
             else:
@@ -757,9 +760,6 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
                 except AttributeError:   # backend without async D2H
                     pass
                 landing.append(p)
-        if not pending and busy_since is not None:
-            device_busy_s += clk() - busy_since
-            busy_since = None
         return retired
 
     ai, done = 0, 0
@@ -783,16 +783,18 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
                 # covers the in-flight bound so the slot cannot alias a
                 # batch the device still reads), timed H2D commit, then
                 # launch-only dispatch.
+                idx = len(dispatch_order)
                 t_dispatch = clk()
-                buf, _ = g.ring.stage([f.rf for f in batch])
-                t = time.perf_counter()
-                dev = g.engine.place(buf)
-                h2d_s += time.perf_counter() - t
-                out = g.engine.dispatch_staged(dev, policy.max_batch)
-                if busy_since is None:
-                    busy_since = t_dispatch
+                with TraceAnnotation("serve.stage", idx=idx, group=g.key):
+                    buf, _ = g.ring.stage([f.rf for f in batch])
+                with TraceAnnotation("serve.place", idx=idx, group=g.key):
+                    t = time.perf_counter()
+                    dev = g.engine.place(buf)
+                    h2d_s += time.perf_counter() - t
+                with TraceAnnotation("serve.launch", idx=idx, group=g.key):
+                    out = g.engine.dispatch_staged(dev, policy.max_batch)
                 pending.append(_Pending(group=g, batch=batch, out=out,
-                                        t_dispatch=t_dispatch))
+                                        t_dispatch=t_dispatch, idx=idx))
                 dispatch_order.append(
                     [[specs[f.stream].stream_id, f.seq] for f in batch])
                 g.n_pending += 1
@@ -813,14 +815,12 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
             # (``poll_base``) while the next scheduling decision is
             # imminent, stretching toward the idle horizon (capped)
             # when it is not, so low-rate streams stop spinning the
-            # core the staging ring shares. These sleeps happen UNDER
-            # the busy clock and are charged against the overlap
-            # fraction — host idle while device works.
+            # core the staging ring shares.
             dt = _poll_grain(clk(),
                              _idle_horizon(frames, ai, groups, delay_s),
                              base=poll_base)
-            time.sleep(dt)
-            sleep_while_busy_s += dt
+            with TraceAnnotation("serve.poll"):
+                time.sleep(dt)
             continue
 
         # Fully idle: sleep to the next arrival or the earliest
@@ -830,7 +830,8 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
         if hz is not None:
             dt = hz - clk()
             if dt > 0:
-                time.sleep(min(dt, 0.05))
+                with TraceAnnotation("serve.idle"):
+                    time.sleep(min(dt, 0.05))
 
     wall = clk()
     resources = meter.stop()
@@ -907,18 +908,6 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
         "acq_per_s_ci": bootstrap_ci([acqs / wall]).json_dict(),
         "deadline_miss_rate": (misses / with_budget if with_budget
                                else 0.0),
-        "device_busy_s": device_busy_s,
-        "device_busy_frac": device_busy_s / wall,
-        "overlap_frac": max(0.0, (device_busy_s - sleep_while_busy_s)
-                            / wall),
-        # Degenerate one-window intervals, like acq_per_s_ci: the
-        # benchmark's --repeats replaces them with real bootstraps so
-        # the gate can apply CI-exclusion to the overlap columns too.
-        "device_busy_frac_ci": bootstrap_ci(
-            [device_busy_s / wall]).json_dict(),
-        "overlap_frac_ci": bootstrap_ci(
-            [max(0.0, (device_busy_s - sleep_while_busy_s) / wall)]
-        ).json_dict(),
         # Host transfer telemetry: all three are host-thread-sequential
         # slices of the wall, so the fraction is well-defined in [0,1].
         # Under the async drain d2h_s is only the residual tail the

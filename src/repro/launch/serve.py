@@ -506,9 +506,7 @@ def main() -> None:
               f"(warm-up {stats['warmup_s']:.2f}s ahead of window)")
         ifo = stats["in_flight_occupancy"]
         print(f"overlap: in_flight={stats['in_flight']} "
-              f"mean_depth={ifo['mean_depth']:.2f} "
-              f"device_busy={stats['device_busy_frac']:.2f} "
-              f"overlap_frac={stats['overlap_frac']:.2f}")
+              f"mean_depth={ifo['mean_depth']:.2f}")
         print(f"transfer: drain={stats['drain']} "
               f"stage_copy={stats['stage_copy_s'] * 1e3:.2f}ms "
               f"h2d={stats['h2d_s'] * 1e3:.2f}ms "

@@ -239,24 +239,15 @@ def test_run_gate_multiple_current_artifacts(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Drain mode in the cell identity + overlap-telemetry gating
+# Drain mode in the cell identity
 # ---------------------------------------------------------------------------
 
-def _mt_overlap(clients, max_batch, delay_ms, in_flight, acq_per_s, *,
-                drain=None, busy=None, busy_runs=None, overlap=None,
-                overlap_runs=None, runs=None):
+def _mt_drain(clients, max_batch, delay_ms, in_flight, acq_per_s, *,
+              drain=None, runs=None):
     rec = _mt(clients, max_batch, delay_ms, in_flight, acq_per_s,
               runs=runs)
     if drain is not None:
         rec["drain"] = drain
-    if busy is not None:
-        rec["device_busy_frac"] = busy
-        if busy_runs is not None:
-            rec["device_busy_frac_ci"] = _ci(busy_runs)
-    if overlap is not None:
-        rec["overlap_frac"] = overlap
-        if overlap_runs is not None:
-            rec["overlap_frac_ci"] = _ci(overlap_runs)
     return rec
 
 
@@ -264,8 +255,8 @@ def test_gate_multitenant_drain_is_part_of_cell_identity():
     """An async-drain window must never gate against a blocking
     baseline cell — and an unstamped (pre-drain) record IS the blocking
     cell it ran as."""
-    base = [_mt_overlap(2, 4, 5.0, 2, 100.0, drain="block"),
-            _mt_overlap(2, 4, 5.0, 2, 120.0, drain="async")]
+    base = [_mt_drain(2, 4, 5.0, 2, 100.0, drain="block"),
+            _mt_drain(2, 4, 5.0, 2, 120.0, drain="async")]
     assert mt_key(base[0]) != mt_key(base[1])
     assert mt_key(base[0])[5] == "block"
     # unstamped record == block: backwards-compatible identity
@@ -273,76 +264,12 @@ def test_gate_multitenant_drain_is_part_of_cell_identity():
 
     # an async row at block speed satisfies its own cell but must not
     # stand in for the missing block cell
-    cur = [_mt_overlap(2, 4, 5.0, 2, 115.0, drain="async")]
+    cur = [_mt_drain(2, 4, 5.0, 2, 115.0, drain="async")]
     failures = gate_multitenant(base, cur, factor=2.0)
     assert len(failures) == 1
     assert "missing" in failures[0] and "drain=block" in failures[0]
-    cur.append(_mt_overlap(2, 4, 5.0, 2, 95.0, drain="block"))
+    cur.append(_mt_drain(2, 4, 5.0, 2, 95.0, drain="block"))
     assert gate_multitenant(base, cur, factor=2.0) == []
-
-
-def test_gate_overlap_telemetry_regression_fails_named():
-    """device_busy_frac / overlap_frac are gated like acq/s: a cell
-    whose overlap machinery decayed fails by NAME even when acq/s still
-    passes (arrival-rate slack can hide the loss)."""
-    base = [_mt_overlap(2, 4, 5.0, 2, 100.0, runs=[99.0, 100.0, 101.0],
-                        drain="async",
-                        busy=0.8, busy_runs=[0.79, 0.80, 0.81],
-                        overlap=0.6, overlap_runs=[0.59, 0.60, 0.61])]
-    # acq/s healthy, overlap collapsed far past the factor-2 floor.
-    cur = [_mt_overlap(2, 4, 5.0, 2, 98.0, runs=[97.0, 98.0, 99.0],
-                       drain="async",
-                       busy=0.78, busy_runs=[0.77, 0.78, 0.79],
-                       overlap=0.1, overlap_runs=[0.09, 0.10, 0.11])]
-    failures = gate_multitenant(base, cur, factor=2.0)
-    assert len(failures) == 1
-    assert "overlap_frac" in failures[0]
-    assert "entirely below" in failures[0]
-    assert "drain=async" in failures[0]
-    assert "(mean-only)" not in failures[0]
-
-    # Same shape through device_busy_frac.
-    cur[0]["device_busy_frac"] = 0.2
-    cur[0]["device_busy_frac_ci"] = _ci([0.19, 0.20, 0.21])
-    failures = gate_multitenant(base, cur, factor=2.0)
-    assert len(failures) == 2
-    assert any("device_busy_frac" in f for f in failures)
-
-
-def test_gate_overlap_noise_straddle_passes():
-    """The CI-exclusion rule applies to the overlap columns too: a
-    noisy dip whose ratio interval straddles the floor is not a
-    regression."""
-    base = [_mt_overlap(2, 4, 5.0, 2, 100.0, runs=[99.0, 100.0, 101.0],
-                        overlap=0.5, overlap_runs=[0.45, 0.50, 0.55],
-                        busy=0.8, busy_runs=[0.79, 0.80, 0.81])]
-    cur = [_mt_overlap(2, 4, 5.0, 2, 100.0, runs=[99.0, 100.0, 101.0],
-                       overlap=0.3, overlap_runs=[0.2, 0.3, 0.55],
-                       busy=0.8, busy_runs=[0.79, 0.80, 0.81])]
-    assert gate_multitenant(base, cur, factor=2.0) == []
-
-
-def test_gate_overlap_zero_baseline_skipped():
-    """A legitimately synchronous baseline cell (depth-1 overlap run
-    mean 0.0) is skipped for that metric — the ratio is undefined — and
-    a pre-telemetry baseline row (no overlap keys at all) gates acq/s
-    only."""
-    base = [_mt_overlap(2, 4, 5.0, 1, 100.0, runs=[99.0, 100.0, 101.0],
-                        overlap=0.0, overlap_runs=[0.0, 0.0, 0.0],
-                        busy=0.8, busy_runs=[0.79, 0.80, 0.81])]
-    cur = [_mt_overlap(2, 4, 5.0, 1, 98.0, runs=[97.0, 98.0, 99.0],
-                       overlap=0.0, overlap_runs=[0.0, 0.0, 0.0],
-                       busy=0.78, busy_runs=[0.77, 0.78, 0.79])]
-    assert gate_multitenant(base, cur, factor=2.0) == []
-
-    # Pre-telemetry baseline: no overlap keys anywhere, still gates.
-    assert gate_multitenant([_mt(2, 4, 5.0, 1, 100.0)],
-                            [_mt(2, 4, 5.0, 1, 95.0)], factor=2.0) == []
-    # Current missing a metric the baseline carries: named failure.
-    cur_missing = [_mt(2, 4, 5.0, 1, 98.0)]
-    failures = gate_multitenant(base, cur_missing, factor=2.0)
-    assert len(failures) == 1
-    assert "device_busy_frac" in failures[0]
 
 
 def test_run_gate_multiple_multitenant_artifacts(tmp_path):
@@ -351,14 +278,14 @@ def test_run_gate_multiple_multitenant_artifacts(tmp_path):
     steady and transfer-telemetry smoke files in one invocation)."""
     baseline = {"results": [],
                 "multitenant": [
-                    _mt_overlap(2, 4, 5.0, 2, 100.0, drain="block"),
-                    _mt_overlap(2, 4, 5.0, 2, 110.0, drain="async")]}
+                    _mt_drain(2, 4, 5.0, 2, 100.0, drain="block"),
+                    _mt_drain(2, 4, 5.0, 2, 110.0, drain="async")]}
     (tmp_path / "base.json").write_text(json.dumps(baseline))
     (tmp_path / "mt_block.ndjson").write_text(
-        json.dumps(_mt_overlap(2, 4, 5.0, 2, 95.0, drain="block"))
+        json.dumps(_mt_drain(2, 4, 5.0, 2, 95.0, drain="block"))
         + "\n")
     (tmp_path / "mt_async.ndjson").write_text(
-        json.dumps(_mt_overlap(2, 4, 5.0, 2, 105.0, drain="async"))
+        json.dumps(_mt_drain(2, 4, 5.0, 2, 105.0, drain="async"))
         + "\n")
 
     failures = run_gate(str(tmp_path / "base.json"),

@@ -99,7 +99,8 @@ def test_multitenant_emitter_validates(mt_records):
         assert rec["clients"] == 2
         assert rec["in_flight"] == depth
         assert rec["warmup_s"] >= 0.0
-        assert 0.0 <= rec["overlap_frac"] <= rec["device_busy_frac"] <= 1.0
+        assert 0.0 <= rec["transfer_frac"] <= 1.0
+        assert rec["acq_per_s_ci"]["n_runs"] >= 1
         assert set(rec["per_stream"]) == {"probe0", "probe1"}
         for g in rec["groups"].values():
             assert g["plan"]["variant"] == "dynamic"
@@ -123,15 +124,15 @@ def test_validator_rejects_multitenant_overlap_violations(mt_records):
     with pytest.raises(SchemaError, match="missing required key"):
         validate_record(rec)
 
-    for key in ("warmup_s", "device_busy_s", "device_busy_frac",
-                "overlap_frac", "in_flight_occupancy"):
+    for key in ("warmup_s", "acq_per_s_ci", "transfer_frac",
+                "in_flight_occupancy"):
         rec = copy.deepcopy(base)
         del rec[key]
         with pytest.raises(SchemaError, match="missing required key"):
             validate_record(rec)
 
     rec = copy.deepcopy(base)
-    rec["device_busy_frac"] = 1.5
+    rec["transfer_frac"] = 1.5
     with pytest.raises(SchemaError, match=r"fraction in \[0, 1\]"):
         validate_record(rec)
 
@@ -372,8 +373,8 @@ def test_numpy_scalars_do_not_sneak_past_json():
 
 def test_multitenant_requires_transfer_and_drain_stamp(mt_records):
     """The host-transfer telemetry is REQUIRED on multitenant rows: a
-    record without its drain mode, transfer seconds, or overlap CI
-    blocks could not be gated against the transfer baseline."""
+    record without its drain mode, transfer seconds, or throughput CI
+    block could not be gated against the transfer baseline."""
     import copy
 
     base = mt_records[0]
@@ -381,12 +382,10 @@ def test_multitenant_requires_transfer_and_drain_stamp(mt_records):
     for key in ("stage_copy_s", "h2d_s", "d2h_s"):
         assert base[key] >= 0.0
     assert 0.0 <= base["transfer_frac"] <= 1.0
-    assert base["device_busy_frac_ci"]["n_runs"] >= 1
-    assert base["overlap_frac_ci"]["n_runs"] >= 1
+    assert base["acq_per_s_ci"]["n_runs"] >= 1
 
     for key in ("drain", "stage_copy_s", "h2d_s", "d2h_s",
-                "transfer_frac", "device_busy_frac_ci",
-                "overlap_frac_ci"):
+                "transfer_frac", "acq_per_s_ci"):
         rec = copy.deepcopy(base)
         del rec[key]
         with pytest.raises(SchemaError, match="missing required key"):
