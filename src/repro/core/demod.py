@@ -3,7 +3,8 @@
 Stages (all static, deterministic):
   1. pointwise mix with the precomputed carrier (cos / -sin at f0),
   2. FIR low-pass + decimation as an explicitly ordered shift-and-add
-     (a strided 1-D conv with the tap accumulation order pinned).
+     (a strided 1-D conv with the tap accumulation order pinned), read
+     in polyphase form.
 
 The carrier vectors and FIR taps are init-time constants (paper §II-C).
 Complex IQ is carried as a trailing (re, im) axis — no complex dtypes.
@@ -16,7 +17,6 @@ from typing import Dict
 import numpy as np
 
 import jax.numpy as jnp
-from jax import lax
 
 from repro.core.config import UltrasoundConfig
 
@@ -56,20 +56,34 @@ def rf_to_iq(consts: Dict[str, jnp.ndarray], rf: jnp.ndarray,
     conv-based reference could never be matched bitwise by a fused kernel.
     Pinning the tap accumulation order makes the demod bit-identical in
     every execution context at identical cost (k FMAs per output sample).
+
+    The FIR is read in polyphase form, so no tap is a strided slice: the
+    padded signal is reshaped to (n_q, decim, ...), and tap
+    t = decim * a + r reads the unit-stride window p[a:a + n_s, r], the
+    same samples a stride-decim slice from t would gather. On the TPU a
+    strided slice of the axial axis is a gather across lanes that XLA
+    neither fuses nor costs; unit-stride windows fuse with the
+    accumulation into one pass. The RF and the carrier are zero-padded
+    before the mix (0 * 0 is the +0.0 a pad of the mixed signal holds),
+    so the pad fuses with the int16 conversion instead of copying the
+    mixed signal once more.
     """
     n_l, n_c, n_f = rf.shape
-    x = rf.astype(jnp.float32)
-    mixed = x[..., None] * consts["carrier"][:, None, None, :]  # (n_l,c,f,2)
-
     lpf = consts["lpf"]                                        # (k,)
     k = lpf.shape[0]
-    pad_lo, pad_hi = _same_pad(n_l, k, decim)
-    m = jnp.pad(mixed, ((pad_lo, pad_hi), (0, 0), (0, 0), (0, 0)))
     n_s = -(-n_l // decim)
+    n_q = n_s + (k - 1) // decim       # phases cover every tap's window
+    pad_lo, _ = _same_pad(n_l, k, decim)
+    axial = (pad_lo, n_q * decim - n_l - pad_lo)
+    x = jnp.pad(rf.astype(jnp.float32), (axial, (0, 0), (0, 0)))
+    carrier = jnp.pad(consts["carrier"], (axial, (0, 0)))
+    mixed = x[..., None] * carrier[:, None, None, :]  # (n_q*decim,c,f,2)
+
+    p = mixed.reshape(n_q, decim, n_c, n_f, 2)
     acc = jnp.zeros((n_s, n_c, n_f, 2), jnp.float32)
     for t in range(k):  # static unroll; ascending tap order is the contract
-        acc = acc + lpf[t] * lax.slice_in_dim(
-            m, t, t + (n_s - 1) * decim + 1, stride=decim, axis=0)
+        a, r = divmod(t, decim)
+        acc = acc + lpf[t] * p[a:a + n_s, r]
     return acc
 
 

@@ -8,13 +8,15 @@ attached — the kernels the executor runs, shaped as it runs them
 (vmapped over the acquisition batch). Nothing executes.
 """
 
+import re
+
 import pytest
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import lowering
+from repro.core import lowering, stages
 from repro.core.config import Modality, Variant, paper_config
 
 BATCH = 4          # the serving pad shape of chip_smoke.py
@@ -128,3 +130,28 @@ def test_fused_kernel_refused_at_paper_geometry_compiles_below(
         _compile(lambda *a: fp.fused_rf_to_power(
             *a, decim=cfg.decim, interpret=False),
             *tables, s((cfg.wall_filter_taps,)), rf)
+
+
+def test_served_cnn_demod_has_no_strided_slice(one_chip, no_compile_cache):
+    """The served CNN B-mode graph reads the FIR taps as unit-stride
+    windows: on the TPU a strided slice of the axial axis is a gather
+    across lanes, one unfused pass per tap."""
+    cfg = paper_config(variant=Variant.CNN)
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    consts = {"carrier": s((cfg.n_l, 2)), "lpf": s((cfg.lpf_taps,)),
+              "interp_matrix": s((cfg.n_c, cfg.n_pix, cfg.n_s, 2))}
+    fn = jax.vmap(stages.graph_fn(cfg), in_axes=(None, 0))
+    text = jax.jit(fn).lower(
+        consts, s((BATCH,) + cfg.rf_shape, jnp.int16)).compile().as_text()
+
+    demod_ops = [line for line in text.splitlines()
+                 if re.search(r'op_name="[^"]*demod', line)]
+    assert demod_ops, "no instruction carries the demod scope"
+    strided = [line.split("=")[0].strip() for line in demod_ops
+               if re.search(r"= \S+ slice\(", line)
+               and any(int(step) > 1 for step in re.findall(
+                   r"\[\d+:\d+:(\d+)\]", line))]
+    assert not strided, strided
