@@ -301,8 +301,10 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, *, devices,
     ctx = types.SimpleNamespace(
         stats=stats, window_s=window_s, latencies_s=latencies,
         served=n_served,
-        served_bytes=sum(served[s.stream_id] * s.cfg.input_bytes
-                         for s in specs),
+        # The bytes of the RF the benchmark made, never the program's
+        # own count of its input.
+        served_bytes=sum(served[s.stream_id]
+                         * pool_rf[s.frame_seed(0)].nbytes for s in specs),
         setup_s=t_open - t_start,
         flops=sum(served[s.stream_id]
                   * work.flops_per_acquisition(pipe, s.cfg.modality.value)

@@ -8,7 +8,7 @@ import shutil
 
 import pytest
 
-from bench import run, spec
+from bench import rf, run, spec
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -89,14 +89,23 @@ def test_a_new_metric_file_is_found_by_name(small_cell, cpu, tmp_path,
             shutil.copy(os.path.join(spec.METRICS_DIR, name), tmp_path)
     (tmp_path / "served_count.x-1.py").write_text(
         "def read(ctx):\n    return ctx.served\n")
+    (tmp_path / "served_bytes.py").write_text(
+        "def read(ctx):\n    return ctx.served_bytes\n")
     monkeypatch.setattr(spec, "METRICS_DIR", str(tmp_path))
     cell = small_cell()
     cell.end_to_end = cell.end_to_end + [
-        {"name": "served_count.x-1", "unit": "acq", "better": "higher",
-         "bound": 0.01, "source": "host_clock"}]
+        {"name": name, "unit": unit, "better": "higher", "bound": 0.01,
+         "source": "host_clock"}
+        for name, unit in (("served_count.x-1", "acq"), ("served_bytes", "B"))]
     res = run.run_cell(cell, 9, 0.1, False, devices=cpu, log=lambda s: None)
     assert res["metrics"]["served_count.x-1"] == {"value": 16.0,
                                                   "unit": "acq"}
+    # The served bytes are the benchmark's own RF's, not the program's
+    # count: 16 acquisitions of int16 (n_l, n_c, n_f).
+    g = cell.config["pipeline"]
+    rf_bytes = rf.make_pool(g, cell.config["rf"], [1])[0].nbytes
+    assert rf_bytes == g["n_l"] * g["n_c"] * g["n_f"] * 2
+    assert res["metrics"]["served_bytes"]["value"] == 16.0 * rf_bytes
     assert list(res)[-1] == "checks"
     assert set(res) >= {"correct", "attempted", "failed", "metrics",
                         "device"}
