@@ -51,8 +51,8 @@ def main():
     const_shardings = jax.tree.map(
         lambda a: NamedSharding(mesh, P()), consts_abs)
     # the big interpolation operator shards its pixel dim over model
-    const_shardings["interp_matrix"] = NamedSharding(
-        mesh, P(None, "model", None, None))
+    for half in ("interp_re", "interp_im"):
+        const_shardings[half] = NamedSharding(mesh, P(None, None, "model"))
     rf_sharding = NamedSharding(mesh, P("data"))
 
     with jax.set_mesh(mesh):

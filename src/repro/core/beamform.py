@@ -68,12 +68,16 @@ def beamform_dynamic(cfg: UltrasoundConfig, consts: Dict[str, jnp.ndarray],
 
 def beamform_cnn(cfg: UltrasoundConfig, consts: Dict[str, jnp.ndarray],
                  iq: jnp.ndarray) -> jnp.ndarray:
-    M = consts["interp_matrix"]                          # (n_c, n_pix, n_s, 2)
+    m_re, m_im = consts["interp_re"], consts["interp_im"]  # (n_c, n_s8, n_pix)
+    # The operator's sample axis is zero-padded to a multiple of 8; pad
+    # IQ to match (zero rows times zero rows add exactly nothing).
+    pad = m_re.shape[1] - iq.shape[0]
+    iq = jnp.pad(iq, ((0, pad), (0, 0), (0, 0), (0, 0)))
     # Two real einsums realize the complex matmul; each is a stack of
     # per-channel (n_pix x n_s) @ (n_s x n_f) matmuls == 1x1 convolutions.
-    a = jnp.einsum("cps,scfr->pfr", M[..., 0], iq,
+    a = jnp.einsum("csp,scfr->pfr", m_re, iq,
                    precision=F32)                        # M_re * (IQre, IQim)
-    b = jnp.einsum("cps,scfr->pfr", M[..., 1], iq,
+    b = jnp.einsum("csp,scfr->pfr", m_im, iq,
                    precision=F32)                        # M_im * (IQre, IQim)
     return cnn_ops.cpack(a[..., 0] - b[..., 1], a[..., 1] + b[..., 0])
 

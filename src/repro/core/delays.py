@@ -83,33 +83,47 @@ def compute_delay_tables(cfg: UltrasoundConfig) -> DelayTables:
 # ---------------------------------------------------------------------------
 
 
-def interp_matrix(cfg: UltrasoundConfig, tables: DelayTables) -> np.ndarray:
-    """Complex DAS operator as a dense (n_c, n_pix, n_s, 2) tensor.
-
-    Row (c, p) has two nonzeros (linear interpolation) scaled by apodization
-    and rotated by the steering phasor:
+def interp_taps(tables: DelayTables) -> Tuple[np.ndarray, np.ndarray]:
+    """The two nonzeros of each row (p, c) of the complex DAS operator.
 
         M[c, p, s] = apod * rot * ((1-frac) [s == idx] + frac [s == idx+1])
+
+    Returns ``idx`` (n_pix, n_c) and ``w`` (2, 2, n_pix, n_c) f32, where
+    ``w[k, r]`` is component r (0 re, 1 im) of the weight at sample
+    ``idx + k``. Both operator forms (dense CNN, banded BSR) place these.
+    """
+    re = tables.rot[..., 0] * tables.apod
+    im = tables.rot[..., 1] * tables.apod
+    lo, hi = 1.0 - tables.frac, tables.frac
+    w = np.stack([np.stack([re * lo, im * lo]), np.stack([re * hi, im * hi])])
+    return tables.idx, w
+
+
+def interp_operator(cfg: UltrasoundConfig,
+                    tables: DelayTables) -> Tuple[np.ndarray, np.ndarray]:
+    """Complex DAS operator as its real and imaginary halves, each a dense
+    (n_c, n_s8, n_pix) f32 tensor; n_s8 is n_s rounded up to a multiple
+    of 8 and the padded sample rows are zero.
 
     Applying it is a per-channel (n_pix x n_s) @ (n_s x n_f) complex matmul
     — i.e. a 1x1 convolution with n_s input channels and n_pix output
     channels, the canonical CNN re-expression of a gather (TINA-style).
+    The halves are stored apart, pixels minor and samples second-minor,
+    because that is the order the TPU's contractions read: an interleaved
+    (..., 2) operator is re-written in every call to split it, and a
+    second-minor axis that is not a multiple of 8 is laid out elsewhere.
     """
-    n_pix, n_c, n_s = cfg.n_pix, cfg.n_c, cfg.n_s
-    M = np.zeros((n_c, n_pix, n_s, 2), dtype=np.float32)
-    rows = np.arange(n_pix)
-    for c in range(n_c):
-        w = tables.apod[:, c]
-        re = tables.rot[:, c, 0] * w
-        im = tables.rot[:, c, 1] * w
-        i0 = tables.idx[:, c]
-        f = tables.frac[:, c]
-        # scatter-add the two interpolation taps (init-time numpy, untimed)
-        np.add.at(M[c, :, :, 0], (rows, i0), re * (1.0 - f))
-        np.add.at(M[c, :, :, 1], (rows, i0), im * (1.0 - f))
-        np.add.at(M[c, :, :, 0], (rows, i0 + 1), re * f)
-        np.add.at(M[c, :, :, 1], (rows, i0 + 1), im * f)
-    return M
+    n_pix, n_c = cfg.n_pix, cfg.n_c
+    n_s8 = -(-cfg.n_s // 8) * 8
+    halves = np.zeros((2, n_c, n_s8, n_pix), dtype=np.float32)
+    idx, w = interp_taps(tables)
+    chans, rows = np.arange(n_c)[None, :], np.arange(n_pix)[:, None]
+    for k in (0, 1):
+        for r in (0, 1):
+            # each (c, s, p) is hit at most once; added into zeros, a -0.0
+            # weight is stored as +0.0 (init-time numpy, untimed)
+            np.add.at(halves[r], (chans, idx + k, rows), w[k, r])
+    return halves[0], halves[1]
 
 
 # ---------------------------------------------------------------------------
@@ -149,9 +163,12 @@ def bsr_operator(cfg: UltrasoundConfig, tables: DelayTables) -> BsrOperator:
     n_sb = (n_s + bs - 1) // bs
     pad_p, pad_s = n_pb * bp, n_sb * bs
 
-    dense = interp_matrix(cfg, tables)  # (n_c, n_pix, n_s, 2)
     dense_p = np.zeros((n_c, pad_p, pad_s, 2), dtype=np.float32)
-    dense_p[:, :n_pix, :n_s] = dense
+    idx, w = interp_taps(tables)
+    chans, rows = np.arange(n_c)[None, :], np.arange(n_pix)[:, None]
+    for k in (0, 1):
+        for r in (0, 1):
+            np.add.at(dense_p, (chans, rows, idx + k, r), w[k, r])
     # (n_c, n_pb, bp, n_sb, bs, 2) block view
     blk = dense_p.reshape(n_c, n_pb, bp, n_sb, bs, 2)
     occupied = np.abs(blk).sum(axis=(2, 4, 5)) > 0  # (n_c, n_pb, n_sb)

@@ -70,7 +70,7 @@ CONSTS_CACHE_STATS = ConstsCacheStats()
 # Bump whenever the *meaning* of precomputed constants changes (delay-table
 # math, interp-matrix layout, BSR packing) — the config hash alone cannot
 # see code changes, and a stale disk entry would silently corrupt images.
-CONSTS_SCHEMA = "consts-v2"     # v2: BSR blocks stored [re | im]
+CONSTS_SCHEMA = "consts-v3"     # v3: CNN operator as re, im halves
 
 # LRU memory tier, bounded in bytes: a paper-scale variant sweep must not
 # pin multi-GB CNN operators for process lifetime. Entries larger than the

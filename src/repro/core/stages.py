@@ -86,7 +86,8 @@ def _beamform_consts(cfg: UltrasoundConfig) -> Dict[str, np.ndarray]:
         consts.update(idx=tables.idx, frac=tables.frac,
                       apod=tables.apod, rot=tables.rot)
     elif cfg.variant == Variant.CNN:
-        consts["interp_matrix"] = delays.interp_matrix(cfg, tables)
+        consts["interp_re"], consts["interp_im"] = \
+            delays.interp_operator(cfg, tables)
     elif cfg.variant == Variant.SPARSE:
         op = delays.bsr_operator(cfg, tables)
         consts["bsr_blocks"] = op.blocks

@@ -10,6 +10,7 @@ attached — the kernels the executor runs, shaped as it runs them
 
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -132,26 +133,81 @@ def test_fused_kernel_refused_at_paper_geometry_compiles_below(
             *tables, s((cfg.wall_filter_taps,)), rf)
 
 
-def test_served_cnn_demod_has_no_strided_slice(one_chip, no_compile_cache):
+def _compile_served_cnn(sharding, modality):
+    """The served CNN graph at the paper geometry, vmapped at BATCH as
+    the executor runs it, compiled from shapes alone."""
+    cfg = paper_config(variant=Variant.CNN, modality=modality)
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    consts = {k: s(v.shape, v.dtype) for stage in stages.build_graph(cfg)
+              if stage is not stages.BEAMFORM
+              for k, v in stage.init_consts(cfg).items()}
+    half = s((cfg.n_c, -(-cfg.n_s // 8) * 8, cfg.n_pix))
+    consts.update(interp_re=half, interp_im=half)
+    fn = jax.vmap(stages.graph_fn(cfg), in_axes=(None, 0))
+    return cfg, jax.jit(fn).lower(
+        consts, s((BATCH,) + cfg.rf_shape, jnp.int16)).compile()
+
+
+@pytest.fixture(scope="module")
+def served_cnn(one_chip, no_compile_cache):
+    """(cfg, compiled) of the served CNN graph per modality, compiled
+    once for the module (about 45 s each on the CPU)."""
+    done = {}
+
+    def get(modality):
+        if modality not in done:
+            done[modality] = _compile_served_cnn(one_chip, modality)
+        return done[modality]
+    return get
+
+
+def _scoped(text, scope):
+    return [line for line in text.splitlines()
+            if re.search(rf'op_name="[^"]*{scope}', line)]
+
+
+def test_served_cnn_demod_has_no_strided_slice(served_cnn):
     """The served CNN B-mode graph reads the FIR taps as unit-stride
     windows: on the TPU a strided slice of the axial axis is a gather
     across lanes, one unfused pass per tap."""
-    cfg = paper_config(variant=Variant.CNN)
-
-    def s(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    consts = {"carrier": s((cfg.n_l, 2)), "lpf": s((cfg.lpf_taps,)),
-              "interp_matrix": s((cfg.n_c, cfg.n_pix, cfg.n_s, 2))}
-    fn = jax.vmap(stages.graph_fn(cfg), in_axes=(None, 0))
-    text = jax.jit(fn).lower(
-        consts, s((BATCH,) + cfg.rf_shape, jnp.int16)).compile().as_text()
-
-    demod_ops = [line for line in text.splitlines()
-                 if re.search(r'op_name="[^"]*demod', line)]
+    _, compiled = served_cnn(Modality.BMODE)
+    demod_ops = _scoped(compiled.as_text(), "demod")
     assert demod_ops, "no instruction carries the demod scope"
     strided = [line.split("=")[0].strip() for line in demod_ops
                if re.search(r"= \S+ slice\(", line)
                and any(int(step) > 1 for step in re.findall(
                    r"\[\d+:\d+:(\d+)\]", line))]
     assert not strided, strided
+
+
+@pytest.mark.parametrize("modality", [Modality.BMODE, Modality.DOPPLER],
+                         ids=["bmode", "doppler"])
+def test_served_cnn_beamform_reads_operator_as_stored(served_cnn, modality):
+    """The einsums read the operator's re and im halves where they lie:
+    no beamform instruction writes an operator-sized array (a per-batch
+    split or relayout), no operator-sized scratch, and each half laid
+    out channel, sample, pixel from major to minor. A second-minor axis
+    of 334 samples would be laid out with the channels there instead,
+    and the einsums would cost about 1.7 times as much."""
+    cfg, compiled = served_cnn(modality)
+    n_op = cfg.n_c * cfg.n_pix * cfg.n_s
+    beamform_ops = _scoped(compiled.as_text(), "beamform")
+    assert beamform_ops, "no instruction carries the beamform scope"
+    big = []
+    for line in beamform_ops:
+        out = re.match(r"\s*(?:ROOT )?(\S+) = (.*?) [a-z][\w-]*\(", line)
+        for dims in re.findall(r"[a-z]\w*\[([\d,]*)\]", out.group(2)):
+            if np.prod([int(d) for d in dims.split(",") if d]) >= n_op:
+                big.append(out.group(1))
+    assert not big, big
+
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < n_op * 2 * 4 / 10, temp
+
+    consts_formats = compiled.input_formats[0][0]
+    for name in ("interp_re", "interp_im"):
+        assert consts_formats[name].layout.major_to_minor == (0, 1, 2), \
+            (name, consts_formats[name].layout)
