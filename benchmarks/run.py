@@ -4,7 +4,6 @@ Prints ``name,us_per_call,derived`` CSV lines:
   table1_* : Table I  — variants x modalities, end-to-end (CPU stand-in)
   table2_* : Table II — portability (CPU measured + TPU predicted)
   table3_* : Table III — throughput context vs prior work
-  stream_* : sustained streaming throughput (batched stage-graph engine)
   lm_*     : zoo throughput smoke (tokens/s on reduced configs)
 
 ``--json PATH`` writes a BENCH_*.json-compatible results file (name,
@@ -17,7 +16,7 @@ budget used for the deadline-miss rate.
 policy and ``--variant auto`` hands the choice to the planner
 (repro.core.plan); the resolved plan is stamped into every telemetry
 record. ``--lowering {xla,pallas}`` pins the beamform stage's operator
-lowering (repro.core.lowering) for the table1/stream sections — pallas
+lowering (repro.core.lowering) for the table1 section — pallas
 sweeps only the variants that register a Pallas kernel, so the
 variant x lowering matrix is benchmarkable end to end (interpret mode
 off-TPU). ``--fusion {none,fused,both}`` routes the demod+beamform+head
@@ -103,25 +102,25 @@ def main() -> None:
                     help="frame budget per forward pass (miss-rate metric)")
     ap.add_argument("--plan", default="fixed",
                     choices=["fixed", "heuristic", "autotune"],
-                    help="variant-resolution policy for the table1/stream "
-                         "sections (repro.core.plan)")
+                    help="variant-resolution policy for the table1 "
+                         "section (repro.core.plan)")
     ap.add_argument("--variant", default=None,
                     choices=["dynamic", "cnn", "sparse", "auto"],
-                    help="single variant for the table1/stream sections "
+                    help="single variant for the table1 section "
                          "(auto = planner picks); default: sweep all "
                          "three. table2's dynamic-vs-cnn comparison is "
                          "fixed by construction")
     ap.add_argument("--lowering", default=None,
                     choices=["xla", "pallas"],
                     help="pin the beamform stage's operator lowering for "
-                         "the table1/stream sections (pallas: only the "
+                         "the table1 section (pallas: only the "
                          "variants registering a kernel run; interpret "
                          "mode off-TPU); default: planner-resolved")
     ap.add_argument("--fusion", default="none",
                     choices=["none", "fused", "both"],
                     help="route the demod+beamform+head span through the "
-                         "fused Pallas megakernel for the table1/stream "
-                         "sections ('both' sweeps unfused and fused per "
+                         "fused Pallas megakernel for the table1 "
+                         "section ('both' sweeps unfused and fused per "
                          "cell; cells with no fused registration emit "
                          "explicit skipped lines)")
     ap.add_argument("--precision", default="f32",
@@ -131,8 +130,7 @@ def main() -> None:
                          "needs --fusion fused/both — the xla references "
                          "are f32-only and their cells are skipped")
     ap.add_argument("--only", default="all",
-                    choices=["all", "table1", "table2", "table3",
-                             "stream", "lm"],
+                    choices=["all", "table1", "table2", "table3", "lm"],
                     help="run a single benchmark section")
     args = ap.parse_args()
     from repro.core.aot import configure_persistent_cache
@@ -169,8 +167,8 @@ def main() -> None:
     if args.merge_into:
         open(args.merge_into).close()   # must already exist (run --json)
 
-    from benchmarks import stream_throughput, table1_variants, \
-        table2_portability, table3_comparison
+    from benchmarks import table1_variants, table2_portability, \
+        table3_comparison
 
     print("name,us_per_call,derived")
     t1 = []
@@ -199,20 +197,6 @@ def main() -> None:
     if on("table3"):
         for line in table3_comparison.run(t1):
             print(line)
-    stream_records = []
-    if on("stream"):
-        stream_lines, stream_records = stream_throughput.run(
-            paper_scale=args.paper, fast=args.fast,
-            deadline_ms=args.deadline_ms,
-            policy=args.plan, variant=variant,
-            lowering=args.lowering,
-            # "both" streams the fused program — the new cell; the
-            # unfused stream is the long-standing default row.
-            fusion="fused" if args.fusion != "none" else "none",
-            precision=args.precision)
-        for line in stream_lines:
-            print(line)
-            sys.stdout.flush()
     if on("lm"):
         for line in _lm_smoke_bench():
             print(line)
@@ -222,13 +206,12 @@ def main() -> None:
         from repro.bench import write_json, write_ndjson
         if args.json:
             write_json(args.json, t1,
-                       extra={"stream": stream_records,
-                              "deadline_ms": args.deadline_ms,
+                       extra={"deadline_ms": args.deadline_ms,
                               "plan_policy": args.plan,
                               "provenance": ["python -m benchmarks.run "
                                              + " ".join(sys.argv[1:])]})
         if args.ndjson:
-            write_ndjson(args.ndjson, t1, extra_records=stream_records)
+            write_ndjson(args.ndjson, t1)
 
     if args.merge_into:
         import json

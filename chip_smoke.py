@@ -299,7 +299,8 @@ def smoke_four_chips(base) -> bool:
         # each device must hold its own row of the batch and of the
         # output, and memory of its own beyond the window's baseline.
         batch = np.zeros((MAX_BATCH,) + cfg.rf_shape, cfg.rf_dtype)
-        out = jax.block_until_ready(engine.dispatch_padded(batch, MAX_BATCH))
+        out = jax.block_until_ready(
+            engine.dispatch_staged(engine.place(batch), MAX_BATCH))
         shards = {s.device: s.data.shape[0] for s in out.addressable_shards}
         _say(shard_rows=json.dumps([shards.get(d) for d in devices]))
         checks["memory"] = check_memory(sharded, devices, baseline)

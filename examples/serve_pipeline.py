@@ -1,8 +1,8 @@
 """Serving drivers for both system halves:
 
-1. Ultrasound: stream batched RF acquisitions through the stage-graph
-   engine (serve_ultrasound_stream, 2 batches in flight) and report
-   sustained FPS / MB/s plus the completion-latency distribution.
+1. Ultrasound: one probe streaming RF acquisitions through the serving
+   loop (serve_multitenant with one stream, 2 batches in flight) and
+   report sustained FPS / MB/s plus the completion-latency distribution.
 2. LM: slot-batched greedy decoding with prefill + KV cache (qwen3 smoke
    config) — the decode-cell path of the dry-run, runnable on CPU.
 
@@ -10,24 +10,26 @@
 """
 
 from repro.core import tiny_config
-from repro.launch.serve import serve_ultrasound_stream
+from repro.launch.scheduler import BatchPolicy, StreamSpec, serve_multitenant
 
 
-def serve_ultrasound(n_batches: int = 8, batch: int = 4):
+def serve_ultrasound(n_frames: int = 32, max_batch: int = 4):
     cfg = tiny_config(nz=32, nx=32, n_f=8, n_c=16)
-    stats = serve_ultrasound_stream(
-        cfg, batch=batch, n_batches=n_batches, depth=2, deadline_s=0.05)
+    probe = StreamSpec("probe0", cfg, fps=1e9, n_frames=n_frames,
+                       deadline_ms=200.0)
+    stats = serve_multitenant(
+        [probe], policy=BatchPolicy(max_batch=max_batch), in_flight=2)
     lat = stats["latency"]
     print(f"ultrasound {stats['name']}: {stats['acquisitions']} acquisitions "
           f"({stats['frames']} frames) FPS={stats['fps']:.1f} "
           f"MB/s={stats['sustained_mbps']:.2f} "
-          f"p50={lat.p50_s * 1e3:.2f}ms p95={lat.p95_s * 1e3:.2f}ms "
-          f"miss_rate={lat.miss_rate:.2f}")
+          f"p50={lat['p50_s'] * 1e3:.2f}ms p95={lat['p95_s'] * 1e3:.2f}ms "
+          f"miss_rate={stats['deadline_miss_rate']:.2f}")
 
 
 def serve_lm():
     from repro.configs import get_smoke
-    from repro.launch.serve import serve_session
+    from repro.train.serving import serve_session
     cfg = get_smoke("qwen3-8b")
     out, stats = serve_session(cfg, requests=8, batch=4, prompt_len=32,
                                max_new=16)

@@ -325,14 +325,11 @@ class BenchResult:
         return lines
 
 
-def write_ndjson(path: str, results: List["BenchResult"],
-                 extra_records: Optional[List[dict]] = None) -> None:
+def write_ndjson(path: str, results: List["BenchResult"]) -> None:
     with open(path, "w") as f:
         for r in results:
             for line in r.ndjson_lines():
                 f.write(line + "\n")
-        for rec in (extra_records or []):
-            f.write(json.dumps(rec) + "\n")
 
 
 def write_json(path: str, results: List["BenchResult"],

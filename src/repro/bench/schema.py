@@ -1,13 +1,13 @@
 """NDJSON telemetry schema validation (`repro-bench-v1`).
 
 One schema, every emitter: `benchmarks/run.py` (summary / sample /
-stage + stream records), `benchmarks/stream_throughput.py`,
-`benchmarks/scaling.py`, and `benchmarks/multitenant.py` all funnel
-through `validate_record`, and the CI smoke rows assert their artifact
-files with the module CLI instead of ad-hoc inline asserts:
+stage records) and `benchmarks/multitenant.py` both funnel through
+`validate_record`, and the CI smoke rows assert their artifact files
+with the module CLI instead of ad-hoc inline asserts:
 
   PYTHONPATH=src python -m repro.bench.schema BENCH_ci.ndjson \
-      SCALING_ci.ndjson --require-kind scaling --require-multidevice
+      MULTITENANT_ci.ndjson --require-kind summary \
+      --require-kind multitenant
 
 Validation is structural — required keys and JSON types per record
 ``kind``, plus the nested `plan` (PipelinePlan.json_dict), `resources`
@@ -149,21 +149,6 @@ RECORD_KEYS: Dict[str, Dict[str, str]] = {
     },
     "sample": {"name": "str", "run": "int", "t_s": "real"},
     "stage": {"name": "str", "stage": "str", **LATENCY_KEYS},
-    "stream": {
-        "name": "str", "batch": "int", "n_batches": "int", "depth": "int",
-        "plan": "dict", "wall_s": "real", "acquisitions": "int",
-        "frames": "int", "sustained_mbps": "real", "fps": "real",
-        "acq_per_s": "real", "latency": "dict", "resources": "dict",
-    },
-    "scaling": {
-        "name": "str", "plan": "dict", "devices": "int",
-        "batch_per_device": "int", "batch": "int", "n_batches": "int",
-        "wall_s": "real", "fps": "real", "sustained_mbps": "real",
-        "peak_memory_bytes": "int?", "memory_source": "str?",
-        "energy_joules": "real?", "joules_per_frame": "real?",
-        "speedup_vs_single": "real?", "scale_efficiency": "real?",
-        "latency": "dict",
-    },
     "multitenant": {
         "name": "str", "clients": "int", "policy": "dict",
         "in_flight": "int", "wall_s": "real", "warmup_s": "real",
@@ -394,30 +379,19 @@ def main(argv: Optional[Tuple[str, ...]] = None) -> int:
                     metavar="KIND",
                     help="fail unless at least one record of KIND exists "
                          "across the given files (repeatable)")
-    ap.add_argument("--require-multidevice", action="store_true",
-                    help="fail unless some record ran on >= 2 devices")
     args = ap.parse_args(argv)
 
     totals: Dict[str, int] = {}
-    multidevice = False
     try:
         for path in args.paths:
             counts = validate_ndjson(path)
             for k, v in counts.items():
                 totals[k] = totals.get(k, 0) + v
-            if args.require_multidevice and not multidevice:
-                with open(path) as f:
-                    multidevice = any(
-                        json.loads(line).get("devices", 1) >= 2
-                        for line in f if line.strip())
             print(f"{path}: " + ", ".join(
                 f"{v} {k}" for k, v in sorted(counts.items())) + " ok")
         for kind in args.require_kind:
             if totals.get(kind, 0) == 0:
                 raise SchemaError(f"no {kind!r} records in {args.paths}")
-        if args.require_multidevice and not multidevice:
-            raise SchemaError(
-                f"no multi-device (devices >= 2) record in {args.paths}")
     except SchemaError as e:
         print(f"schema violation: {e}", file=sys.stderr)
         return 1

@@ -27,11 +27,11 @@ Three pieces:
   * `aot_warm(engine, pad_to)` — lower + compile the executor's
     fixed-shape padded dispatch program for ``(pad_to, *rf_shape)``
     WITHOUT executing it, install the executable on the engine (its
-    ``dispatch_padded`` / ``call_padded`` prefer it over re-tracing
-    through jit), optionally run one zero batch to pre-touch the
-    allocator, and return an `AotProgram` carrying the measured
-    ``compile_s`` / ``warmup_s`` — the number the scheduler stamps
-    instead of silently excluding.
+    ``dispatch_staged`` prefers it over re-tracing through jit),
+    optionally run one zero batch to pre-touch the allocator, and
+    return an `AotProgram` carrying the measured ``compile_s`` /
+    ``warmup_s`` — the number the scheduler stamps instead of silently
+    excluding.
   * `warm_pool(specs, ...)` — the serving front door: one warm
     executor per distinct plan-resolved config group of a stream set,
     keyed exactly like the scheduler groups
@@ -153,11 +153,11 @@ def aot_warm(engine, pad_to: int, *, execute: bool = True) -> AotProgram:
     Lowers and compiles ``engine.jitted`` for a ``(pad_to, *rf_shape)``
     RF batch via the AOT path (`.lower().compile()`), so the cost is
     paid — and *measured* — here, never on a tenant's first frame. The
-    executable is installed on the engine: `dispatch_padded` /
-    `call_padded` at this shape run it directly, skipping jit's
-    trace-cache lookup. With ``execute`` (default) one zero batch runs
-    through the fresh executable so first-dispatch allocator work is
-    also out of the serving window; both costs land in ``warmup_s``.
+    executable is installed on the engine: `dispatch_staged` at this
+    shape runs it directly, skipping jit's trace-cache lookup. With
+    ``execute`` (default) one zero batch runs through ``place`` and the
+    fresh executable so first-dispatch allocator work is also out of
+    the serving window; both costs land in ``warmup_s``.
     """
     if pad_to < 1:
         raise ValueError(f"pad_to must be >= 1 (got {pad_to})")
@@ -170,12 +170,12 @@ def aot_warm(engine, pad_to: int, *, execute: bool = True) -> AotProgram:
     compile_s = time.perf_counter() - t0
     engine.install_aot(pad_to, compiled)
     if execute:
-        jax.block_until_ready(
-            engine.dispatch_padded(np.zeros(shape, dtype), pad_to))
+        jax.block_until_ready(engine.dispatch_staged(
+            engine.place(np.zeros(shape, dtype)), pad_to))
     warmup_s = time.perf_counter() - t0
     return AotProgram(
         key=engine.cfg.canonical_hash(), pad_to=pad_to,
-        devices=getattr(engine, "n_devices", 1),
+        devices=engine.n_devices,
         compile_s=compile_s, warmup_s=warmup_s, cache_dir=cache_dir,
         executable=compiled)
 
@@ -245,18 +245,13 @@ def warm_pool(specs: Sequence, *, max_batch: int, devices=None,
     to extend it incrementally (already-warm groups are not
     recompiled), e.g. across the cells of a benchmark sweep.
     """
-    from repro.core.executor import (BatchedExecutor, ShardedExecutor,
-                                     _resolve_donate)
+    from repro.core.executor import (_resolve_donate, make_executor,
+                                     mesh_size)
     from repro.core.pipeline import _resolve_plan
 
     if max_batch < 1:
         raise ValueError(f"max_batch must be >= 1 (got {max_batch})")
-    sharded = devices is not None and len(devices) > 1
-    n_devices = len(devices) if sharded else 1
-    if sharded and max_batch % n_devices:
-        raise ValueError(
-            f"max_batch={max_batch} must be a multiple of "
-            f"n_devices={n_devices} for sharded dispatch")
+    n_devices = mesh_size(devices, max_batch)
     pool = pool if pool is not None else WarmPool()
     for spec in specs:
         plan = _resolve_plan(spec.cfg, None, plan_policy)
@@ -264,10 +259,9 @@ def warm_pool(specs: Sequence, *, max_batch: int, devices=None,
                n_devices, _resolve_donate(donate, plan))
         if key in pool:
             continue
-        engine = (ShardedExecutor(spec.cfg, devices=devices, plan=plan,
-                                  donate=donate)
-                  if sharded else BatchedExecutor(spec.cfg, plan=plan,
-                                                  donate=donate))
+        engine = make_executor(spec.cfg, devices=devices,
+                               max_batch=max_batch, plan=plan,
+                               donate=donate)
         program = aot_warm(engine, max_batch)
         pool.put(key, WarmEntry(engine=engine, program=program))
     return pool

@@ -8,38 +8,37 @@ device* so throughput scales with hardware instead of clock speed.
 
 Public API
 ----------
+`make_executor`  — the serving tier's one executor choice: two or more
+    ``devices`` give a `ShardedExecutor` over them (``max_batch`` must
+    divide by their count, `mesh_size`), anything else a
+    `BatchedExecutor` on the default device. The scheduler and the warm
+    pool both build through it, so their pool keys cannot disagree.
 `BatchedExecutor`  — init once, jit once, run (B, n_l, n_c, n_f)
     batches many times on the default device. The batch axis carries the
     logical "batch" sharding name, so under an active mesh binding
     (runtime/sharding.py) it composes with the LM half's meshes.
 
-Both executors expose the serving tier's dispatch granularities:
-``__call__`` (synchronous semantics, caller blocks when it reads),
-``call_padded`` (fixed-shape ragged dispatch, valid rows sliced off —
-the one-batch-at-a-time scheduler entry), ``dispatch_padded`` (the
-ASYNC form of call_padded: returns the *padded, unsynchronized* device
-array immediately so the host keeps coalescing and launching while the
-device executes — the caller slices valid rows after it drains; see
-repro.launch.scheduler's in-flight ring), and the zero-copy staged
-pair ``place`` + ``dispatch_staged`` (the batch was already padded
-into a `repro.core.staging.StagingRing` slot: `place` is the timed H2D
-commit, `dispatch_staged` is launch-only — `dispatch_padded` is now
-exactly ``dispatch_staged(place(_pad_rows(...)))``). Donation stays
-safe across all of them: every dispatch consumes a freshly-placed
-device batch, never a caller-retained device array (host ring slots
-are reused, their device copies are not). `install_aot` (fed by
+A batch reaches the device one way: ``place`` + ``dispatch_staged``.
+The batch was already padded to ``pad_to`` rows in a
+`repro.core.staging.StagingRing` slot; `place` is the timed H2D commit
+and `dispatch_staged` is launch-only, returning the padded, unsynced
+device array (the caller slices valid rows after it drains).
+Donation stays safe: every dispatch consumes a freshly-placed device
+batch, never a caller-retained device array (host ring slots are
+reused, their device copies are not). `install_aot` (fed by
 repro.core.aot) pins an ahead-of-time-compiled executable for one
-padded shape; the padded entry points prefer it over re-entering jit.
+padded shape; `dispatch_staged` prefers it over re-entering jit.
+``__call__`` is the one-shot oracle the tests compare against: any
+B >= 1, valid rows only.
 `ShardedExecutor`  — the same contract, data-parallel over an explicit
     1-D ``jax.sharding.Mesh`` of local devices ("data" axis): consts are
     replicated, the acquisition batch axis is split via `NamedSharding`,
-    and outputs come back batch-sharded. Uneven batches (B % devices
-    != 0) are zero-padded to the next multiple and the padding is
-    sliced off the result — callers never see it. On hosts with one
-    physical device, force a multi-device CPU mesh anywhere with
+    and outputs come back batch-sharded. ``__call__`` zero-pads uneven
+    batches (B % devices != 0) to the next multiple and slices the
+    padding off the result. On hosts with one physical device, force a
+    multi-device CPU mesh anywhere with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set before
-    JAX initializes; see benchmarks/scaling.py and
-    tests/test_sharded_executor.py).
+    JAX initializes; see tests/test_sharded_executor.py).
 
 Both executors map the composed stage graph over the leading batch axis:
 
@@ -94,36 +93,6 @@ def _mapped_graph_fn(cfg: UltrasoundConfig):
     return mapped
 
 
-def _pad_rows(rf_batch, pad_to: int) -> tuple:
-    """Zero-pad a ragged batch up to ``pad_to`` rows; returns (batch, b).
-
-    Shared by the executors' ``call_padded`` fixed-shape dispatch: the
-    multi-tenant scheduler coalesces heterogeneous arrivals into batches
-    of any occupancy 1..pad_to, but every dispatch must hit the SAME
-    compiled program — a recompile per occupancy would stall the serving
-    loop. Pad rows are zeros; per-example mapping (vmap / lax.map) keeps
-    them from influencing the valid rows, and callers slice them off.
-
-    Host (numpy) batches pad on the host: the concatenate then costs a
-    memcpy instead of an op-by-op XLA program per distinct occupancy —
-    which would be exactly the hidden first-dispatch compile the AOT
-    warm-start contract forbids. Device arrays keep the jnp path (their
-    pad program caches after one occupancy-shaped compile).
-    """
-    b = rf_batch.shape[0]
-    if b < 1:
-        raise ValueError("empty RF batch")
-    if b > pad_to:
-        raise ValueError(
-            f"batch of {b} exceeds pad_to={pad_to} — the scheduler must "
-            "never coalesce past its policy's max_batch")
-    if b == pad_to:
-        return rf_batch, b
-    xp = np if isinstance(rf_batch, np.ndarray) else jnp
-    fill = xp.zeros((pad_to - b,) + rf_batch.shape[1:], rf_batch.dtype)
-    return xp.concatenate([rf_batch, fill]), b
-
-
 def _resolve_donate(donate: Optional[bool], plan) -> bool:
     """Donation precedence: constructor arg > plan > backend default.
 
@@ -139,6 +108,8 @@ def _resolve_donate(donate: Optional[bool], plan) -> bool:
 
 class BatchedExecutor:
     """Init once, jit once, run (B, n_l, n_c, n_f) batches many times."""
+
+    n_devices = 1
 
     def __init__(self, cfg: UltrasoundConfig, *,
                  donate: Optional[bool] = None, plan=None,
@@ -189,34 +160,6 @@ class BatchedExecutor:
         fn = self._aot.get(pad_to, self._fn)
         return fn(self.consts, dev_batch)
 
-    def dispatch_padded(self, rf_batch, pad_to: int) -> jnp.ndarray:
-        """Async fixed-shape dispatch: the PADDED, UNSYNCED output.
-
-        The in-flight serving entry: pads to ``pad_to`` rows, launches
-        (through the AOT executable when one is installed), and returns
-        the device array without blocking or slicing — the caller
-        tracks how many rows are valid and slices after it drains
-        (`jax.block_until_ready` / ``.is_ready()``). Donation-safe:
-        the launched buffer is the freshly-padded batch, never an array
-        the caller still holds.
-        """
-        rf_batch, _ = _pad_rows(rf_batch, pad_to)
-        return self.dispatch_staged(self.place(rf_batch), pad_to)
-
-    def call_padded(self, rf_batch: jnp.ndarray,
-                    pad_to: int) -> jnp.ndarray:
-        """Fixed-shape dispatch of a ragged batch (B <= pad_to rows).
-
-        Heterogeneous-arrival entry point for the dynamic-batching
-        scheduler (repro.launch.scheduler): zero-pads the batch to
-        ``pad_to`` rows so every occupancy 1..pad_to reuses one compiled
-        program, then slices the valid rows off the result. Pad rows
-        cost compute, never a recompile.
-        """
-        b = rf_batch.shape[0]
-        out = self.dispatch_padded(rf_batch, pad_to)
-        return out[:b] if b != pad_to else out
-
     @property
     def jitted(self):
         """The compiled (consts, rf_batch) -> images callable."""
@@ -242,11 +185,10 @@ class ShardedExecutor:
     device's batch shard with no cross-device communication (the
     pipeline is embarrassingly parallel over acquisitions).
 
-    ``devices=None`` takes every local device. Uneven batches are
-    zero-padded up to a device multiple and the pad rows sliced off the
-    returned images, so any B >= 1 is accepted — at the cost of one
-    wasted device-row of compute for remainders (callers streaming for
-    throughput should keep B a multiple of ``n_devices``).
+    ``devices=None`` takes every local device. ``__call__`` zero-pads
+    uneven batches up to a device multiple and slices the pad rows off
+    the returned images, so any B >= 1 is accepted; `dispatch_staged`
+    takes only a ``pad_to`` that divides by ``n_devices``.
     """
 
     def __init__(self, cfg: UltrasoundConfig, *,
@@ -293,6 +235,7 @@ class ShardedExecutor:
         self._aot: dict = {}              # pad_to -> AOT-compiled executable
 
     def _pad(self, rf_batch: jnp.ndarray) -> tuple:
+        """Zero rows up to a device multiple, for `__call__`."""
         b = rf_batch.shape[0]
         if b < 1:
             raise ValueError("empty RF batch")
@@ -307,23 +250,6 @@ class ShardedExecutor:
         rf_batch, b, pad = self._pad(rf_batch)
         out = self._fn(self.consts, rf_batch)
         return out[:b] if pad else out
-
-    def dispatch(self, rf_batch: jnp.ndarray) -> jnp.ndarray:
-        """Like ``__call__`` but keeps the (padded) batch-sharded result.
-
-        The streaming loop uses this to track per-device shards of the
-        in-flight output; B must already be a device multiple so no
-        host-side slicing re-synchronizes the stream.
-        """
-        b = rf_batch.shape[0]
-        if b < 1:
-            raise ValueError("empty RF batch")
-        if b % self.n_devices:
-            raise ValueError(
-                f"dispatch() needs batch % n_devices == 0 "
-                f"(got B={b}, n_devices={self.n_devices}); use __call__ "
-                "for remainder-padded one-shot execution")
-        return self._fn(self.consts, rf_batch)
 
     def install_aot(self, pad_to: int, compiled) -> None:
         """Pin an AOT-compiled SPMD executable for the ``pad_to`` shape
@@ -354,36 +280,6 @@ class ShardedExecutor:
         fn = self._aot.get(pad_to, self._fn)
         return fn(self.consts, dev_batch)
 
-    def dispatch_padded(self, rf_batch, pad_to: int) -> jnp.ndarray:
-        """Async fixed-shape dispatch: the PADDED, UNSYNCED device array.
-
-        Sharded counterpart of `BatchedExecutor.dispatch_padded`:
-        ``pad_to`` must be a device multiple (one SPMD shape per mesh).
-        """
-        if pad_to % self.n_devices:
-            raise ValueError(
-                f"dispatch_padded needs pad_to % n_devices == 0 "
-                f"(got pad_to={pad_to}, n_devices={self.n_devices})")
-        rf_batch, _ = _pad_rows(rf_batch, pad_to)
-        return self.dispatch_staged(self.place(rf_batch), pad_to)
-
-    def call_padded(self, rf_batch: jnp.ndarray,
-                    pad_to: int) -> jnp.ndarray:
-        """Fixed-shape dispatch of a ragged batch (B <= pad_to rows).
-
-        The sharded counterpart of `BatchedExecutor.call_padded`:
-        ``pad_to`` must be a device multiple so the one compiled SPMD
-        shape splits evenly across the mesh (the scheduler enforces
-        ``max_batch % n_devices == 0`` at construction).
-        """
-        if pad_to % self.n_devices:
-            raise ValueError(
-                f"call_padded needs pad_to % n_devices == 0 "
-                f"(got pad_to={pad_to}, n_devices={self.n_devices})")
-        b = rf_batch.shape[0]
-        out = self.dispatch_padded(rf_batch, pad_to)
-        return out[:b] if b != pad_to else out
-
     @property
     def jitted(self):
         """The compiled SPMD (consts, rf_batch) -> images callable."""
@@ -398,3 +294,30 @@ class ShardedExecutor:
     def name(self) -> str:
         return (f"{self.cfg.name}:{self.cfg.variant.value}"
                 f":{self.cfg.exec_map}:d{self.n_devices}")
+
+
+def mesh_size(devices: Optional[Sequence], max_batch: int) -> int:
+    """Devices a serving batch of ``max_batch`` rows is split across.
+
+    Two or more ``devices``: all of them, and ``max_batch`` must divide
+    by their count (one SPMD shape per mesh). Anything else: 1, the
+    default device. Part of every warm-pool key.
+    """
+    n = len(devices) if devices is not None and len(devices) > 1 else 1
+    if max_batch % n:
+        raise ValueError(
+            f"max_batch={max_batch} must be a multiple of "
+            f"n_devices={n} for sharded dispatch")
+    return n
+
+
+def make_executor(cfg: UltrasoundConfig, *, devices: Optional[Sequence],
+                  max_batch: int, plan=None,
+                  donate: Optional[bool] = None):
+    """The serving executor of ``cfg`` for batches of ``max_batch`` rows:
+    a `ShardedExecutor` over ``devices`` when `mesh_size` is above 1,
+    else a `BatchedExecutor` on the default device."""
+    if mesh_size(devices, max_batch) > 1:
+        return ShardedExecutor(cfg, devices=devices, plan=plan,
+                               donate=donate)
+    return BatchedExecutor(cfg, plan=plan, donate=donate)

@@ -45,8 +45,8 @@ Public API
 ----------
 `plan_pipeline(cfg, policy=...)`        — resolve a config into a plan.
 `PipelinePlan`                          — the frozen result; consumed by
-    `UltrasoundPipeline`, `BatchedExecutor`, `ShardedExecutor`,
-    `serve_ultrasound_stream` and stamped (``json_dict()``) into every
+    `UltrasoundPipeline`, `BatchedExecutor`, `ShardedExecutor` and
+    `serve_multitenant`, and stamped (``json_dict()``) into every
     telemetry record. ``with_devices(n, mesh_shape)`` derives the
     multi-device form the `ShardedExecutor` stamps — ``devices``/
     ``mesh_shape`` keep every NDJSON row attributable to the exact

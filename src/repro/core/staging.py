@@ -3,8 +3,8 @@
 Before this module, every coalesced dispatch paid TWO host copies on
 the admit thread before the device ever saw a byte:
 ``np.stack([f.rf for f in batch])`` materialized a fresh batch array,
-then ``executor._pad_rows`` concatenated it with zeros into a second
-fresh array of the padded shape. Both allocations and both memcpys sat
+then a zero-padding concatenate copied it into a second fresh array of
+the padded shape. Both allocations and both memcpys sat
 on the serving critical path, every batch, forever.
 
 `StagingRing` fuses them into zero extra copies: a ring of

@@ -1,14 +1,13 @@
-"""Multi-tenant dynamic-batching serve scheduler.
+"""Multi-tenant dynamic-batching serve scheduler: the serving loop.
 
-`serve_ultrasound_stream` measures ONE synthetic probe feeding the
-engine as fast as it can. Real deployments (the ROADMAP north star)
-look different: N independent probes — mixed modalities, mixed
-geometries, mixed frame rates — each producing acquisitions on its own
-clock, all contending for the same accelerator. Accelerator serving
-throughput is won or lost in the batching-under-latency-bound policy
-(Jouppi et al.: datacenter inference batches aggressively but bounds
-queue delay), and the determinism contract (TINA lineage, paper §II-C)
-requires that none of that batching changes a single output bit.
+Real deployments (the ROADMAP north star) are N independent probes —
+mixed modalities, mixed geometries, mixed frame rates — each producing
+acquisitions on its own clock, all contending for the same
+accelerator. Accelerator serving throughput is won or lost in the
+batching-under-latency-bound policy (Jouppi et al.: datacenter
+inference batches aggressively but bounds queue delay), and the
+determinism contract (TINA lineage, paper §II-C) requires that none of
+that batching changes a single output bit.
 
 This module is that frontend:
 
@@ -27,24 +26,24 @@ This module is that frontend:
   * `serve_multitenant` — per-config queues: frames are grouped by the
     full canonical config hash (only identical pipelines may share a
     compiled program), coalesced into batches under the policy, and
-    dispatched through the executors' async ``dispatch_padded`` (over a
-    mesh when ``devices`` spans one) at ONE fixed compiled shape per
-    group — occupancy varies, the program never recompiles. Among
-    queues eligible to flush (full, or oldest frame past the delay
-    bound) the oldest head dispatches first — ties on identical head
-    arrival times resolve to the first group in construction order —
-    so a saturated tenant never starves a sparse one (FIFO fairness;
-    frames of one stream never reorder).
+    launched through the executors' ``place`` + async
+    ``dispatch_staged`` (over a mesh when ``devices`` spans one) at ONE
+    fixed compiled shape per group — occupancy varies, the program
+    never recompiles. Among queues eligible to flush (full, or oldest
+    frame past the delay bound) the oldest head dispatches first — ties
+    on identical head arrival times resolve to the first group in
+    construction order — so a saturated tenant never starves a sparse
+    one (FIFO fairness; frames of one stream never reorder).
 
 Dispatch is PIPELINED: up to ``in_flight`` launched batches ride a
 bounded ring as pending completions while the host keeps admitting
-arrivals, coalescing queues, and launching the next eligible batch —
-the `serve_ultrasound_stream` depth-N pattern lifted into the
-coalescing scheduler, so the device no longer idles during host-side
-bookkeeping and vice versa. Completions drain via non-blocking
-readiness checks, oldest-first *per group* (a later batch of a group
-never retires before an earlier one, so a stream's frames can never
-reorder no matter which ring slot settles first); outputs are keyed by
+arrivals, coalescing queues, and launching the next eligible batch, so
+the device does not idle during host-side bookkeeping and vice versa.
+A single probe is one stream: ``serve_multitenant([spec])``.
+Completions drain via non-blocking readiness checks, oldest-first *per
+group* (a later batch of a group never retires before an earlier one,
+so a stream's frames can never reorder no matter which ring slot
+settles first); outputs are keyed by
 (stream, seq), so even a cross-group out-of-order drain leaves no trace
 in the pixels — the determinism oracle holds bit-for-bit at every
 depth. Every group's padded program is compiled AHEAD of the window
@@ -169,10 +168,10 @@ class StreamSpec:
     ``dropped`` telemetry. The decision uses only arrival timestamps,
     never wall clock, so a replay drops the same frames.
 
-    RF content: ``pool`` pre-generated acquisitions cycle like
-    `SyntheticAcquisitionSource` so host-side synthesis stays out of
-    the serving window. The cycle period is ``min(pool, n_frames)``
-    (never more pools than frames are synthesized); frame k carries RF
+    RF content: ``pool`` pre-generated acquisitions cycle, so host-side
+    synthesis stays out of the serving window. The cycle period is
+    ``min(pool, n_frames)`` (never more pools than frames are
+    synthesized); frame k carries RF
     ``synth_rf(cfg, seed=self.frame_seed(k))``, where `frame_seed`
     derives a per-(stream_id, seed) disjoint seed space via
     `repro.data.traces.seed_space` — two tenants never share a
@@ -248,7 +247,8 @@ def make_mixed_streams(n_clients: int, cfg_bmode: UltrasoundConfig,
     `mixed_phase` — the SAME helpers the ``steady`` trace generator
     uses, so a generated steady trace replays this schedule
     bit-identically (equal floats, equal trace_sha256).
-    Used by ``--multitenant`` serving and `benchmarks/multitenant.py`.
+    Used by the serving CLI (`repro.launch.serve`) and
+    `benchmarks/multitenant.py`.
     """
     if n_clients < 1:
         raise ValueError(f"n_clients must be >= 1 (got {n_clients})")
@@ -378,16 +378,11 @@ def _build_groups(specs: Sequence[StreamSpec], policy: BatchPolicy, *,
     (arg > plan > backend default), so a lookup and the engine it would
     otherwise build can never disagree on the donation signature.
     """
-    from repro.core.executor import (BatchedExecutor, ShardedExecutor,
-                                     _resolve_donate)
+    from repro.core.executor import (_resolve_donate, make_executor,
+                                     mesh_size)
     from repro.core.pipeline import _resolve_plan
 
-    sharded = devices is not None and len(devices) > 1
-    n_devices = len(devices) if sharded else 1
-    if sharded and policy.max_batch % n_devices:
-        raise ValueError(
-            f"max_batch={policy.max_batch} must be a multiple of "
-            f"n_devices={n_devices} for sharded dispatch")
+    n_devices = mesh_size(devices, policy.max_batch)
 
     groups: Dict[str, _Group] = {}
     group_of_stream: List[_Group] = []
@@ -405,11 +400,9 @@ def _build_groups(specs: Sequence[StreamSpec], policy: BatchPolicy, *,
                 g = _Group(key, entry.engine.cfg, entry.engine)
                 g.warm_source = "pool"
             else:
-                engine = (ShardedExecutor(spec.cfg, devices=devices,
-                                          plan=plan, donate=donate)
-                          if sharded
-                          else BatchedExecutor(spec.cfg, plan=plan,
-                                               donate=donate))
+                engine = make_executor(spec.cfg, devices=devices,
+                                       max_batch=policy.max_batch,
+                                       plan=plan, donate=donate)
                 g = _Group(key, engine.cfg, engine)
             groups[key] = g
         groups[key].stream_ids.append(spec.stream_id)
@@ -595,12 +588,13 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
     slot is never donated.
 
     ``devices``: a sequence of >= 2 local devices routes dispatch
-    through `ShardedExecutor.dispatch_padded` (``max_batch`` must
-    divide evenly). ``plan_policy`` resolves `Variant.AUTO` tenants
-    (repro.core.plan). ``collect_outputs=True`` additionally returns
-    every served image (``outputs[stream_id][seq]``, numpy) — the hook
-    the determinism-oracle tests compare against the per-frame
-    monolithic reference.
+    through a `ShardedExecutor` over them (``max_batch`` must divide
+    evenly; `repro.core.executor.make_executor` makes the choice).
+    ``plan_policy`` resolves `Variant.AUTO` tenants (repro.core.plan).
+    ``collect_outputs=True`` additionally returns every served image
+    (``outputs[stream_id][seq]``, numpy) — the hook the
+    determinism-oracle tests compare against the per-frame monolithic
+    reference.
 
     Returns a stats dict (schema: `repro.bench.schema`, kind
     "multitenant" once the benchmark stamps it): aggregate + per-stream
@@ -652,14 +646,13 @@ def serve_multitenant(streams: Sequence[StreamSpec], *,
     # and stamped, not silently excluded. Pool hits already paid;
     # misses are warmed here and published back so the next window
     # (the next sweep cell) starts from "pool".
-    n_devices = len(devices) if devices is not None and len(devices) > 1 else 1
     for g in groups:
         if g.warm_source == "pool":
             continue
         prog = aot_warm(g.engine, policy.max_batch)
         g.warmup_s = prog.warmup_s
         if pool is not None:
-            pool.put((g.key, policy.max_batch, n_devices,
+            pool.put((g.key, policy.max_batch, g.engine.n_devices,
                       g.engine.donate),
                      WarmEntry(engine=g.engine, program=prog))
     warmup_s = sum(g.warmup_s for g in groups)

@@ -27,10 +27,10 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 from repro.core import Modality, Variant, tiny_config
 from repro.core.executor import BatchedExecutor
+from repro.core.staging import StagingRing
 from repro.data import synth_rf
 from repro.launch.scheduler import BatchPolicy, StreamSpec, serve_multitenant
 
@@ -50,14 +50,19 @@ def test_aot_warm_bit_identical_to_jit_path():
     assert prog.pad_to == 4 and prog.devices == 1
     assert 4 in aot_eng._aot                # executable installed
 
-    rf = jnp.asarray(np.stack([synth_rf(cfg, seed=s) for s in range(3)]))
-    want = np.asarray(jit_eng.call_padded(rf, 4))
-    got = np.asarray(aot_eng.call_padded(rf, 4))
+    rf = [synth_rf(cfg, seed=s) for s in range(3)]
+
+    def launch(eng, pad_to):
+        ring = StagingRing(pad_to, cfg.rf_shape, cfg.rf_dtype, depth=1)
+        buf, b = ring.stage(rf)
+        return np.asarray(eng.dispatch_staged(eng.place(buf), pad_to))[:b]
+
+    want = launch(jit_eng, 4)
+    got = launch(aot_eng, 4)
     assert got.dtype == want.dtype and got.shape == want.shape
     assert np.array_equal(got, want)
     # Other pad shapes fall back to the jit path transparently.
-    assert np.array_equal(np.asarray(aot_eng.call_padded(rf, 3)),
-                          np.asarray(jit_eng.call_padded(rf, 3)))
+    assert np.array_equal(launch(aot_eng, 3), launch(jit_eng, 3))
 
 
 def test_cache_dir_env_resolution(monkeypatch, tmp_path):
@@ -168,11 +173,12 @@ from repro.data import synth_rf
 cfg = tiny_config(variant=Variant.DYNAMIC)
 eng = BatchedExecutor(cfg)
 prog = aot_warm(eng, 4)          # JAX_COMPILATION_CACHE_DIR set by the test
-rf = np.stack([synth_rf(cfg, seed=s) for s in range(2)])
+rf = np.zeros((4,) + cfg.rf_shape, cfg.rf_dtype)    # 2 frames, 2 pad rows
+rf[:2] = [synth_rf(cfg, seed=s) for s in range(2)]
 times = []
 for _ in range(21):
     t0 = time.perf_counter()
-    jax.block_until_ready(eng.dispatch_padded(rf, 4))
+    jax.block_until_ready(eng.dispatch_staged(eng.place(rf), 4))
     times.append(time.perf_counter() - t0)
 print(json.dumps({
     "compile_s": prog.compile_s, "warmup_s": prog.warmup_s,

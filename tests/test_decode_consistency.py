@@ -51,7 +51,7 @@ def test_prefill_then_decode_matches_forward(arch, kv_variant, key, rng):
         err_msg="prefill last-position logits != forward")
 
     # grow cache and decode the remaining tokens one by one
-    from repro.launch.serve import _grow_cache
+    from repro.train.serving import _grow_cache
     cache = _grow_cache(model, cache, S + extra + 1)
     lengths = jnp.full((B,), S, jnp.int32)
     decode = jax.jit(model.decode_step)

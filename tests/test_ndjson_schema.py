@@ -1,13 +1,11 @@
 """One schema, every emitter: repro.bench.schema applied end to end.
 
 Every NDJSON-producing path — the bench harness's summary / sample /
-stage records (benchmarks/run.py), the streaming records
-(stream_throughput.py), the scaling rows (scaling.py), and the
-multi-tenant scheduler rows (multitenant.py) — is generated here
-in-process at tiny geometry and pushed through the SAME
-`validate_record` that CI runs against the artifact files, so the
-schema cannot fork between what tests check and what CI enforces
-(this replaces the former CI-only inline assert for scaling rows).
+stage records (benchmarks/run.py) and the multi-tenant scheduler rows
+(multitenant.py) — is generated here in-process at tiny geometry and
+pushed through the SAME `validate_record` that CI runs against the
+artifact files, so the schema cannot fork between what tests check and
+what CI enforces.
 """
 
 import json
@@ -56,29 +54,6 @@ def test_write_ndjson_file_validates(tmp_path, bench_result):
     write_ndjson(str(path), [bench_result])
     counts = validate_ndjson(str(path))
     assert counts == {"summary": 1, "sample": 3, "stage": 3}
-
-
-def test_stream_emitter_validates():
-    from benchmarks import stream_throughput
-    _, records = stream_throughput.run(fast=True, cfg=_tiny_cfg())
-    assert records
-    for rec in records:
-        assert validate_record(rec) == "stream"
-        assert rec["plan"]["variant"] == "dynamic"
-        assert rec["resources"]["devices"] >= 1
-
-
-def test_scaling_emitter_validates():
-    from benchmarks import scaling
-    _, records = scaling.run(device_counts=[1], batch_sizes=(1,),
-                             fast=True, cfg=_tiny_cfg())
-    assert records
-    for rec in records:
-        assert validate_record(rec) == "scaling"
-        assert rec["devices"] == 1
-    # The multi-device cells run in CI's forced-2-device smoke row and
-    # are validated there with the same module (python -m
-    # repro.bench.schema SCALING_ci.ndjson --require-multidevice).
 
 
 @pytest.fixture(scope="module")

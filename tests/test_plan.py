@@ -26,7 +26,7 @@ from repro.core import (CONSTS_CACHE_STATS, Modality, UltrasoundPipeline,
                         paper_config, set_consts_cache_dir, tiny_config)
 from repro.core import plan as plan_lib
 from repro.data import synth_rf
-from repro.launch.serve import serve_ultrasound_stream
+from repro.launch.scheduler import BatchPolicy, StreamSpec, serve_multitenant
 
 
 @pytest.fixture(autouse=True)
@@ -333,10 +333,14 @@ def test_bench_result_carries_plan_in_every_ndjson_row():
 
 def test_streaming_stats_carry_resolved_plan():
     cfg = tiny_config(variant=Variant.AUTO)
-    stats = serve_ultrasound_stream(cfg, batch=2, n_batches=3, depth=1,
-                                    policy="heuristic")
-    plan = stats["plan"]
+    stats = serve_multitenant(
+        [StreamSpec("probe0", cfg, fps=1e9, n_frames=6)],
+        policy=BatchPolicy(max_batch=2), in_flight=1,
+        plan_policy="heuristic")
+    (group,) = stats["groups"].values()
+    plan = group["plan"]
     assert plan["policy"] == "heuristic"
     assert Variant(plan["variant"]).concrete
     assert plan["exec_map"] == "vmap"
-    assert "/auto/" not in stats["name"]               # name uses resolved cfg
+    # the stream's stamp names the resolved variant, never "auto"
+    assert stats["per_stream"]["probe0"]["variant"] == plan["variant"]

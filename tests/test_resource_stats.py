@@ -16,7 +16,7 @@ from repro.bench import (NvmlEnergyMeter, ResourceMeter, ResourceStats,
                          bench_callable)
 from repro.bench.resources import device_peak_memory_bytes, live_array_bytes
 from repro.core import tiny_config
-from repro.launch.serve import serve_ultrasound_stream
+from repro.launch.scheduler import BatchPolicy, StreamSpec, serve_multitenant
 
 
 def test_resource_stats_json_nulls_distinguish_unmeasured():
@@ -196,14 +196,16 @@ def test_bench_callable_stamps_resources_into_ndjson():
 
 
 def test_stream_stats_carry_resources():
-    stats = serve_ultrasound_stream(tiny_config(), batch=2, n_batches=4,
-                                    depth=2)
+    stats = serve_multitenant(
+        [StreamSpec("probe0", tiny_config(), fps=1e9, n_frames=8)],
+        policy=BatchPolicy(max_batch=2), in_flight=2)
     res = stats["resources"]
     assert res["peak_memory_bytes"] is not None
     assert res["memory_source"] == "live_arrays"
     assert res["energy_joules"] is None          # graceful off-GPU
-    json.dumps(stats["plan"])                    # stamp stays serializable
-    assert stats["plan"]["devices"] == 1
+    (group,) = stats["groups"].values()
+    json.dumps(group["plan"])                    # stamp stays serializable
+    assert group["plan"]["devices"] == 1
 
 
 def test_meter_survives_broken_energy_backend():

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.core import Modality, Variant, tiny_config
 from repro.core.executor import BatchedExecutor
+from repro.core.staging import StagingRing
 from repro.core.pipeline import init_pipeline, monolithic_pipeline_fn
 from repro.data import synth_rf
 from repro.launch.scheduler import (BatchPolicy, StreamSpec,
@@ -430,41 +431,56 @@ def test_saturated_tenant_cannot_starve_sparse_tenants_frame():
     assert _pick_group([hog, solo], now=1.005, policy=policy) is None
 
 
+def _staged(eng, frames, pad_to):
+    """One batch launched as the serving loop launches it: staged into
+    a ring slot (pad rows zero), `place`, `dispatch_staged`; returns
+    the valid rows."""
+    ring = StagingRing(pad_to, eng.cfg.rf_shape, eng.cfg.rf_dtype, depth=1)
+    buf, b = ring.stage(frames)
+    return np.asarray(eng.dispatch_staged(eng.place(buf), pad_to))[:b]
+
+
 def test_sharded_call_padded_degenerate_single_device_mesh():
-    """ShardedExecutor.call_padded on the 1-device mesh: same contract
-    as the batched path (the true multi-device run is the subprocess
-    test below, same pattern as test_sharded_executor.py)."""
+    """ShardedExecutor's place + dispatch_staged on the 1-device mesh:
+    same contract as the batched path (the true multi-device run is the
+    subprocess test below, same pattern as test_sharded_executor.py)."""
     from repro.core.executor import ShardedExecutor
 
     cfg = tiny_config(variant=Variant.DYNAMIC)
     eng = ShardedExecutor(cfg)        # all local devices (1 in-process)
     if eng.n_devices != 1:            # pragma: no cover - env-dependent
         pytest.skip("main process must see a single device")
-    rf = jnp.asarray(np.stack([synth_rf(cfg, seed=s) for s in range(2)]))
-    out = np.asarray(eng.call_padded(rf, 4))
+    rf = [synth_rf(cfg, seed=s) for s in range(2)]
+    out = _staged(eng, rf, 4)
     assert out.shape[0] == 2
-    assert np.array_equal(out, np.asarray(eng(rf)))
+    assert np.array_equal(out, np.asarray(eng(jnp.asarray(np.stack(rf)))))
     with pytest.raises(ValueError, match="exceeds pad_to"):
-        eng.call_padded(rf, 1)
+        _staged(eng, rf, 1)
 
 
 def test_call_padded_fixed_shape_contract():
-    """The executor's heterogeneous-arrival entry point: any occupancy
-    1..pad_to returns exactly the valid rows, and over- or empty
-    batches are refused."""
+    """The heterogeneous-arrival launch: any occupancy 1..pad_to
+    returns exactly the valid rows (pad rows change nothing), over- or
+    empty batches are refused, and the AOT program takes only its own
+    padded shape."""
+    from repro.core.aot import aot_warm
+
     cfg = tiny_config(variant=Variant.DYNAMIC)
     eng = BatchedExecutor(cfg)
-    rf3 = jnp.asarray(np.stack([synth_rf(cfg, seed=s) for s in range(3)]))
-    full = np.asarray(eng(rf3))
-    padded = np.asarray(eng.call_padded(rf3, 4))
+    rf3 = [synth_rf(cfg, seed=s) for s in range(3)]
+    full = np.asarray(eng(jnp.asarray(np.stack(rf3))))
+    padded = _staged(eng, rf3, 4)
     assert padded.shape == full.shape
     assert np.array_equal(padded, full)
-    one = np.asarray(eng.call_padded(rf3[:1], 4))
+    one = _staged(eng, rf3[:1], 4)
     assert np.array_equal(one[0], full[0])
     with pytest.raises(ValueError, match="exceeds pad_to"):
-        eng.call_padded(rf3, 2)
+        _staged(eng, rf3, 2)
     with pytest.raises(ValueError, match="empty"):
-        eng.call_padded(rf3[:0], 4)
+        _staged(eng, [], 4)
+    aot_warm(eng, 2)
+    with pytest.raises(TypeError, match="compiled with"):
+        eng.dispatch_staged(eng.place(np.stack(rf3)), 2)
 
 
 # ---------------------------------------------------------------------------
@@ -484,6 +500,7 @@ import jax.numpy as jnp
 from repro.core import BatchedExecutor, Modality, ShardedExecutor, \
     Variant, tiny_config
 from repro.core.pipeline import init_pipeline, monolithic_pipeline_fn
+from repro.core.staging import StagingRing
 from repro.data import synth_rf
 from repro.launch.scheduler import BatchPolicy, StreamSpec, \
     serve_multitenant
@@ -491,19 +508,27 @@ from repro.launch.scheduler import BatchPolicy, StreamSpec, \
 out = {"device_count": jax.device_count()}
 cfg = tiny_config(variant=Variant.DYNAMIC)
 
-# call_padded: fixed SPMD shape, valid rows match the batched oracle
+
+def staged(eng, frames, pad_to):
+    ring = StagingRing(pad_to, cfg.rf_shape, cfg.rf_dtype, depth=1)
+    buf, b = ring.stage(frames)
+    return np.asarray(eng.dispatch_staged(eng.place(buf), pad_to))[:b]
+
+
+# place + dispatch_staged: fixed SPMD shape, valid rows match the
+# batched oracle
 eng = ShardedExecutor(cfg)
 oracle = BatchedExecutor(cfg)
 errs = {}
 for B in (1, 3, 4):
-    rf = jnp.stack([jnp.asarray(synth_rf(cfg, seed=i)) for i in range(B)])
-    got = np.asarray(eng.call_padded(rf, 4))
-    want = np.asarray(oracle(rf))
+    rf = [synth_rf(cfg, seed=i) for i in range(B)]
+    got = staged(eng, rf, 4)
+    want = np.asarray(oracle(jnp.asarray(np.stack(rf))))
     errs[str(B)] = [list(got.shape) == list(want.shape),
                     float(np.abs(got - want).max())]
 out["errs"] = errs
 try:
-    eng.call_padded(jnp.stack([jnp.asarray(synth_rf(cfg, seed=0))]), 3)
+    staged(eng, [synth_rf(cfg, seed=0)], 3)
     out["pad_to_odd_raised"] = False
 except ValueError:
     out["pad_to_odd_raised"] = True

@@ -86,7 +86,8 @@ import jax.numpy as jnp
 
 from repro.core import BatchedExecutor, ShardedExecutor, tiny_config
 from repro.data import synth_rf
-from repro.launch.serve import serve_ultrasound_sharded
+from repro.launch.scheduler import BatchPolicy, StreamSpec, \
+    serve_multitenant
 
 out = {"device_count": jax.device_count()}
 
@@ -106,16 +107,18 @@ for B in (4, 3, 1):
                     float(np.abs(got - want).max())]
 out["errs"] = errs
 
-# dispatch() refuses remainders (streaming must stay device-aligned)
+# dispatch_staged refuses a pad_to that is not a device multiple (one
+# SPMD shape per mesh: serving must stay device-aligned)
 try:
-    eng.dispatch(jnp.stack([jnp.asarray(synth_rf(cfg, seed=0))] * 3))
+    eng.dispatch_staged(
+        eng.place(np.stack([synth_rf(cfg, seed=0)] * 3)), 3)
     out["dispatch_remainder_raised"] = False
 except ValueError:
     out["dispatch_remainder_raised"] = True
 
 # one output shard per device
-disp = eng.dispatch(
-    jnp.stack([jnp.asarray(synth_rf(cfg, seed=i)) for i in range(4)]))
+disp = eng.dispatch_staged(
+    eng.place(np.stack([synth_rf(cfg, seed=i) for i in range(4)])), 4)
 out["shard_devices"] = sorted(str(s.device) for s in disp.addressable_shards)
 out["shard_batches"] = [s.data.shape[0] for s in disp.addressable_shards]
 
@@ -130,20 +133,21 @@ out["map_err"] = float(np.abs(np.asarray(eng_m(rf4))
 hlo = eng_m.jitted.lower(eng_m.consts, rf4).compile().as_text()
 out["map_has_allgather"] = "all-gather" in hlo.lower()
 
-# sharded streaming: per-device queues + scale-efficiency fields
-stats = serve_ultrasound_sharded(cfg, batch_per_device=2, n_batches=6,
-                                 depth=2, deadline_s=1.0)
+# one probe served over both devices: every batch split across the mesh
+stats = serve_multitenant(
+    [StreamSpec("probe0", cfg, fps=1e9, n_frames=24)],
+    policy=BatchPolicy(max_batch=4), devices=jax.local_devices(),
+    collect_outputs=True)
 out["stream"] = {
-    "devices": stats["devices"],
-    "batch": stats["batch"],
+    "plan_devices": [g["plan"]["devices"]
+                     for g in stats["groups"].values()],
+    "mesh_shape": [g["plan"]["mesh_shape"]
+                   for g in stats["groups"].values()],
+    "max_batch": stats["policy"]["max_batch"],
     "acquisitions": stats["acquisitions"],
-    "plan_devices": stats["plan"]["devices"],
-    "per_device_n": sorted(v["n"] for v in
-                           stats["per_device_latency"].values()),
-    "n_queues": len(stats["per_device_latency"]),
-    "baseline_fps": stats["baseline_fps"],
-    "speedup_vs_single": stats["speedup_vs_single"],
-    "scale_efficiency": stats["scale_efficiency"],
+    "served": len(stats["outputs"]["probe0"]),
+    "batches": stats["occupancy"]["batches"],
+    "max_occupancy": stats["occupancy"]["max_occupancy"],
     "resources": stats["resources"],
 }
 
@@ -152,10 +156,16 @@ print(json.dumps(out))
 
 
 @pytest.fixture(scope="module")
-def results():
+def results(tmp_path_factory):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
+    # Keep the subprocess's on-disk caches out of the checkout, like
+    # conftest does in-process.
+    env["JAX_COMPILATION_CACHE_DIR"] = str(
+        tmp_path_factory.mktemp("subproc-xla-cache"))
+    env["REPRO_CONSTS_CACHE_DIR"] = str(
+        tmp_path_factory.mktemp("subproc-consts-cache"))
     proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
@@ -175,7 +185,8 @@ def test_allclose_vs_single_device_oracle(results):
 
 
 def test_uneven_remainder_handling(results):
-    # B=3 and B=1 exercised padding above; dispatch() must refuse them
+    # B=3 and B=1 exercised __call__'s padding above; dispatch_staged
+    # must refuse a pad_to that does not split across the mesh
     assert results["dispatch_remainder_raised"] is True
 
 
@@ -193,14 +204,14 @@ def test_exec_map_map_stays_data_parallel(results):
 
 
 def test_sharded_stream_stats(results):
+    """The serving loop over a 2-device mesh: the plan stamp names the
+    mesh and every frame of the probe is served."""
     s = results["stream"]
-    assert s["devices"] == 2 and s["plan_devices"] == 2
-    assert s["batch"] == 4                          # 2 per device x 2
-    assert s["acquisitions"] == 6 * 4
-    assert s["n_queues"] == 2
-    assert s["per_device_n"] == [6, 6]              # every dispatch drained
-    assert s["baseline_fps"] > 0
-    assert s["speedup_vs_single"] == pytest.approx(
-        s["scale_efficiency"] * 2)
+    assert s["plan_devices"] == [2]
+    assert s["mesh_shape"] == [[["data", 2]]]
+    assert s["max_batch"] == 4                      # 2 per device x 2
+    assert s["acquisitions"] == s["served"] == 24   # every frame drained
+    assert s["batches"] >= 24 // 4
+    assert s["max_occupancy"] <= 4
     assert s["resources"]["peak_memory_bytes"] is not None
     assert s["resources"]["energy_joules"] is None  # no NVML on CI/CPU

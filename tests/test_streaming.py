@@ -1,6 +1,9 @@
-"""Streaming serve loop + distribution telemetry (harness upgrade)."""
+"""Streaming serve loop + distribution telemetry, and the serving CLI."""
 
 import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 
@@ -9,8 +12,12 @@ import jax.numpy as jnp
 from repro.bench import bench_callable, bench_stages, latency_stats
 from repro.core import tiny_config
 from repro.data import synth_rf
-from repro.launch.serve import (SyntheticAcquisitionSource,
-                                serve_ultrasound_stream)
+from repro.core.aot import WarmPool
+from repro.launch.scheduler import (BatchPolicy, StreamSpec, _make_frames,
+                                    serve_multitenant)
+
+SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "src")
 
 
 def test_latency_stats_percentiles_and_misses():
@@ -54,31 +61,36 @@ def test_ndjson_telemetry_schema():
 
 
 def test_acquisition_source_shapes_and_cycling():
+    """A stream's RF pool: one acquisition per frame, distinct within
+    the pool, cycling with period ``min(pool, n_frames)``."""
     cfg = tiny_config()
-    src = SyntheticAcquisitionSource(cfg, batch=3, pool=2, seed=7)
-    a, b, c = src.next(), src.next(), src.next()
-    assert a.shape == (3,) + cfg.rf_shape
+    spec = StreamSpec("probe0", cfg, n_frames=3, pool=2, seed=7)
+    frames, dropped = _make_frames([spec])
+    assert dropped == [0] and [f.seq for f in frames] == [0, 1, 2]
+    a, b, c = (f.rf for f in frames)
+    assert a.shape == cfg.rf_shape
     assert not np.array_equal(a, b)                     # distinct sweeps
     assert np.array_equal(a, c)                         # pool of 2 cycles
+    assert spec.frame_seed(0) == spec.frame_seed(2) != spec.frame_seed(1)
 
 
 def test_acquisition_sources_with_nearby_seeds_share_no_frame():
-    """Disjoint per-source seed spaces: the old additive scheme
-    (``seed + b * batch + i``) made two sources whose base seeds differ
-    by less than ``pool * batch`` stream byte-identical frames (source
-    0's frame 2 was source 2's frame 0). Hash-derived seed spaces must
-    never collide across distinct base seeds."""
+    """Disjoint per-stream seed spaces: an additive scheme
+    (``seed + k``) makes two streams whose base seeds differ by less
+    than ``pool`` serve byte-identical frames (seed 0's frame 2 was
+    seed 2's frame 0). Hash-derived seed spaces must never collide
+    across distinct base seeds."""
     cfg = tiny_config()
-    src_a = SyntheticAcquisitionSource(cfg, batch=2, pool=2, seed=0)
-    src_b = SyntheticAcquisitionSource(cfg, batch=2, pool=2, seed=2)
-    frames_a = [f for batch in src_a._pool for f in batch]
-    frames_b = [f for batch in src_b._pool for f in batch]
+    frames_a = [f.rf for f in _make_frames(
+        [StreamSpec("probe0", cfg, n_frames=4, pool=4, seed=0)])[0]]
+    frames_b = [f.rf for f in _make_frames(
+        [StreamSpec("probe0", cfg, n_frames=4, pool=4, seed=2)])[0]]
     for i, fa in enumerate(frames_a):
         for j, fb in enumerate(frames_b):
             assert not np.array_equal(fa, fb), (
-                f"sources seed=0 frame {i} and seed=2 frame {j} are "
+                f"streams seed=0 frame {i} and seed=2 frame {j} are "
                 f"byte-identical")
-    # within one source every pooled frame is still distinct
+    # within one stream every pooled frame is still distinct
     for i, fa in enumerate(frames_a):
         for fb in frames_a[i + 1:]:
             assert not np.array_equal(fa, fb)
@@ -87,27 +99,30 @@ def test_acquisition_sources_with_nearby_seeds_share_no_frame():
 def test_streaming_batched_throughput_beats_single_frame():
     """Acceptance: sustained MB/s at batch N>1 >= single-frame MB/s.
 
-    At tiny geometry the batched engine wins by a wide margin (dispatch
-    overhead dominates), but this is still a wall-clock inequality on a
-    shared machine — so it is asserted with the CI-exclusion rule over
-    three windows per side (fail only when the whole ratio interval
-    says the batched engine is slower), replacing a retry loop that
-    still flaked whenever two windows in a row caught a stall.
+    One backlog probe (every frame queued at once) through the serving
+    loop at max_batch 8 / in_flight 2 against max_batch 1 / in_flight 1.
+    At tiny geometry batching wins by a wide margin (dispatch overhead
+    dominates), but this is still a wall-clock inequality on a shared
+    machine — so it is asserted with the CI-exclusion rule over three
+    windows per side (fail only when the whole ratio interval says the
+    batched loop is slower). One warm pool serves every window, so
+    only the first window of each shape compiles.
     """
     from repro.bench.stats import gate_ratio
 
     cfg = tiny_config()
-    single_mbps, batched_mbps = [], []
-    for _ in range(3):
-        single_mbps.append(serve_ultrasound_stream(
-            cfg, batch=1, n_batches=8, depth=1,
-            deadline_s=1.0)["sustained_mbps"])
-    for _ in range(3):
-        batched_mbps.append(serve_ultrasound_stream(
-            cfg, batch=8, n_batches=8, depth=2,
-            deadline_s=1.0)["sustained_mbps"])
-    batched = serve_ultrasound_stream(cfg, batch=8, n_batches=8, depth=2,
-                                      deadline_s=1.0)
+    pool = WarmPool()
+
+    def window(max_batch, in_flight):
+        probe = StreamSpec("probe0", cfg, fps=1e9, n_frames=64,
+                           deadline_ms=8000.0)
+        return serve_multitenant(
+            [probe], policy=BatchPolicy(max_batch=max_batch),
+            in_flight=in_flight, pool=pool)
+
+    single_mbps = [window(1, 1)["sustained_mbps"] for _ in range(3)]
+    batched_mbps = [window(8, 2)["sustained_mbps"] for _ in range(3)]
+    batched = window(8, 2)
     # higher_is_better at factor 1.0: fail only when the batched/single
     # ratio CI sits entirely below 1.0.
     dec = gate_ratio(single_mbps, batched_mbps, factor=1.0,
@@ -116,6 +131,44 @@ def test_streaming_batched_throughput_beats_single_frame():
     assert batched["acquisitions"] == 64
     assert batched["frames"] == 64 * cfg.n_f
     lat = batched["latency"]
-    assert lat.p50_s <= lat.p95_s <= lat.p99_s
-    assert lat.budget_s == 8 * 1.0                      # batch * deadline
-    assert lat.miss_rate == 0.0
+    assert lat["p50_s"] <= lat["p95_s"] <= lat["p99_s"]
+    probe = batched["per_stream"]["probe0"]
+    assert probe["latency"]["budget_s"] == 8.0
+    assert probe["deadline_miss_rate"] == 0.0
+
+
+def _subprocess_env(tmp_path):
+    """PYTHONPATH at the checkout's src; caches inside ``tmp_path``."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC + (os.pathsep + env["PYTHONPATH"]
+                               if env.get("PYTHONPATH") else "")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla-cache")
+    env["REPRO_CONSTS_CACHE_DIR"] = str(tmp_path / "consts-cache")
+    return env
+
+
+def test_serve_cli_imports_no_language_model_code(tmp_path):
+    """The ultrasound serving CLI loads nothing of the LM tree."""
+    code = ("import sys, repro.launch.serve; "
+            "print(sorted(m for m in sys.modules if m.startswith("
+            "('repro.models', 'repro.configs', 'repro.train'))))")
+    proc = subprocess.run([sys.executable, "-c", code],
+                          env=_subprocess_env(tmp_path),
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == "[]"
+
+
+def test_serve_cli_single_probe_runs(tmp_path):
+    """``--ultrasound --clients 1`` serves one probe through
+    `serve_multitenant` and exits 0."""
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--ultrasound",
+         "--clients", "1", "--frames", "6", "--max-batch", "2",
+         "--deadline-ms", "1000"],
+        env=_subprocess_env(tmp_path), capture_output=True, text=True,
+        timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "multitenant/1streams/1groups/b2" in proc.stdout
+    assert "6 acquisitions" in proc.stdout
+    assert "probe0 [RF2IQ_DAS_BMODE/dynamic" in proc.stdout

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import TrainConfig, get_smoke
-from repro.launch.serve import serve_session
+from repro.train.serving import serve_session
 from repro.launch.train import train_loop
 
 
